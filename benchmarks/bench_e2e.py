@@ -565,7 +565,15 @@ def sharded_pool_points(quick: bool) -> dict:
     interpreters: ``--xla_force_host_platform_device_count`` binds at
     backend init, so each device count needs a fresh process. The
     flagship point (8 stations × 8 devices, one station per device) is
-    in both grids — the acceptance ratio reads from it."""
+    in both grids — the acceptance ratio reads from it.
+
+    CPU only: the children time forced host devices, which next to an
+    accelerator would quietly measure the host instead of the chip."""
+    backend = jax.default_backend()
+    if backend != "cpu":
+        raise RuntimeError(
+            f"the sharded_pool grid runs forced CPU host devices in child "
+            f"processes and cannot measure a {backend} backend")
     root = pathlib.Path(__file__).resolve().parent.parent
     grid = [(2, 4), (8, 8)] if quick else \
         [(1, 8), (2, 8), (4, 8), (8, 8), (8, 16)]

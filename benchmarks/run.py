@@ -18,6 +18,8 @@ import sys
 import time
 import traceback
 
+from repro.compile_cache import enable_compile_cache
+
 
 def main(argv=None) -> None:
     ap = argparse.ArgumentParser()
@@ -32,6 +34,7 @@ def main(argv=None) -> None:
                     help="also run the concurrent serving-tier benchmark "
                          "(BENCH_serve.json)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     t0 = time.time()
     if args.e2e or args.serve:

@@ -6,10 +6,12 @@ Run:  PYTHONPATH=src python examples/dedup_corpus.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.data.dedup import DedupConfig, find_duplicates
 
 
 def main():
+    enable_compile_cache()
     rng = np.random.default_rng(0)
     n, s = 64, 256
     docs = rng.integers(1, 50_000, (n, s)).astype(np.int32)

@@ -18,6 +18,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (AlignConfig, DetectConfig, FingerprintConfig,
                         LSHConfig, SynthConfig, make_dataset)
 from repro.core.detect import detect_events, recall_against_truth, \
@@ -46,6 +47,7 @@ def main():
                     help="in-dispatch occurrence limiter for the optimized "
                          "run (0 = off; host §6.5 filter always applies)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     dataset = make_dataset(SynthConfig(
         duration_s=args.duration, n_stations=3, n_sources=3,

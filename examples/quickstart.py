@@ -5,12 +5,14 @@ Run:  PYTHONPATH=src python examples/quickstart.py
 """
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import (AlignConfig, DetectConfig, FingerprintConfig,
                         LSHConfig, SynthConfig, make_dataset)
 from repro.core.detect import detect_events, recall_against_truth
 
 
 def main():
+    enable_compile_cache()
     # 1. Synthetic network: 3 stations, 3 reoccurring sources, repeating
     #    background noise at station 0 (the Figure-7 pathology).
     dataset = make_dataset(SynthConfig(

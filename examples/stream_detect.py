@@ -28,6 +28,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.fast_seismic import (located_smoke_config, smoke_config,
                                         stream_bounded_smoke_config,
                                         stream_smoke_config)
@@ -48,6 +49,7 @@ def main():
                     help="station geometry + location/magnitude tier "
                          "(implies --bounded)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = located_smoke_config() if args.locate else smoke_config()
     scfg = (stream_bounded_smoke_config() if args.bounded or args.locate

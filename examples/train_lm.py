@@ -8,6 +8,7 @@ Run:  PYTHONPATH=src python examples/train_lm.py --steps 30
 """
 import argparse
 
+from repro.compile_cache import enable_compile_cache
 from repro.launch import train as train_mod
 from repro.models.config import ModelConfig
 
@@ -37,6 +38,7 @@ def main():
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = SCALES[args.model_scale]
     print(f"model: {cfg.name} ({cfg.param_count()/1e6:.1f}M params), "

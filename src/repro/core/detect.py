@@ -154,6 +154,24 @@ def replay_config(lcfg: LSHConfig, block_fingerprints: int = 256,
                                 bucket_cap=lcfg.bucket_cap))
 
 
+def station_stats(waveforms: np.ndarray, fcfg: FingerprintConfig
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Offline §5.2 statistics of every station, (S, n_coeff) median and
+    MAD — the first pass of the two-pass structure, with the per-station
+    sampling key ``detect_events`` has always used. A streaming detector
+    given these (``med_mad``) runs the same per-station binarization as
+    the batch replay."""
+    meds, mads = [], []
+    for st in range(waveforms.shape[0]):
+        coeffs = fp_mod.coeffs_from_waveform(jnp.asarray(waveforms[st]),
+                                             fcfg)
+        med, mad = fp_mod.mad_stats(coeffs, fcfg.mad_sample_rate,
+                                    jax.random.PRNGKey(fcfg.stft_len + st))
+        meds.append(med)
+        mads.append(mad)
+    return jnp.stack(meds), jnp.stack(mads)
+
+
 def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
                   n_partitions: int = 1, scfg=None,
                   keep_pairs: bool = False,
@@ -213,16 +231,8 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
     # traced program as the streaming service (which owns no whole-trace
     # buffer to begin with) rather than a batch-only coeffs-in variant
     with tracer.span("fingerprint_stats"):
-        meds, mads = [], []
-        for st in range(n_stations):
-            coeffs = fp_mod.coeffs_from_waveform(
-                jnp.asarray(waveforms[st]), fcfg)
-            med, mad = fp_mod.mad_stats(
-                coeffs, fcfg.mad_sample_rate,
-                jax.random.PRNGKey(fcfg.stft_len + st))
-            meds.append(med)
-            mads.append(mad)
-        _block(mads[-1])
+        meds, mads = station_stats(waveforms, fcfg)
+        _block(mads)
     with tracer.span("hashgen"):
         mappings = lsh_mod.hash_mappings(fcfg.fp_dim, lcfg)
         _block(mappings)
@@ -380,8 +390,6 @@ def detect_step_sharded(waveforms: jax.Array, med: jax.Array,
 
     from jax.sharding import PartitionSpec as P
 
-    from repro import dist
-
     all_axes = tuple(a for a in ("pod", "data", "model")
                      if a in mesh.shape)
     step = jax.vmap(functools.partial(detect_step, cfg=cfg, **knobs),
@@ -390,7 +398,7 @@ def detect_step_sharded(waveforms: jax.Array, med: jax.Array,
     def per_shard(wf, md, md2):
         return step(wf, md, md2)
 
-    return dist.shard_map(
+    return jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(all_axes, None), P(), P()),
         out_specs=P(all_axes),

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from repro.kernels.ref import dft_matrices
+from repro.kernels.ref import MATMUL_PRECISION, dft_matrices
 from repro.utils import pack_bits
 
 
@@ -126,7 +126,7 @@ def bandpass_kernel(cfg: FingerprintConfig) -> np.ndarray:
 
 def bandpass(x: jax.Array, cfg: FingerprintConfig) -> jax.Array:
     taps = jnp.asarray(bandpass_kernel(cfg))
-    return jnp.convolve(x, taps, mode="same")
+    return jnp.convolve(x, taps, mode="same", precision=MATMUL_PRECISION)
 
 
 # ---------------------------------------------------------------------------
@@ -167,7 +167,7 @@ def spectral_images(spec: jax.Array, cfg: FingerprintConfig) -> jax.Array:
     """(n_frames, B) spectrogram → (n_images, img_freq, img_time)."""
     n_frames, b = spec.shape
     pool = jnp.asarray(_pool_matrix(b, cfg.img_freq))
-    pooled = spec @ pool  # (n_frames, img_freq)
+    pooled = jnp.matmul(spec, pool, precision=MATMUL_PRECISION)
     n_img = (n_frames - cfg.img_time) // cfg.img_hop + 1
     idx = (jnp.arange(n_img)[:, None] * cfg.img_hop
            + jnp.arange(cfg.img_time)[None, :])

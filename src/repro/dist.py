@@ -15,8 +15,7 @@ from typing import Sequence
 
 import jax
 import numpy as np
-from jax.interpreters import pxla
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AbstractMesh, Mesh, NamedSharding, PartitionSpec as P
 
 # mesh axis name of the station-pool shard (stream/fused.py): the leading
 # S axis of the stacked FusedState pytree is split over it
@@ -33,38 +32,6 @@ _UNEVEN: contextvars.ContextVar[bool] = contextvars.ContextVar(
 # gathered per use — the §Perf layout for large-batch dense training).
 _LAYOUT: contextvars.ContextVar[str] = contextvars.ContextVar(
     "repro_layout", default="tp")
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, axis_names=None,
-              check_vma=True):
-    """Version-portable ``shard_map`` (new top-level API vs. experimental).
-
-    jax ≥ 0.5 exposes ``jax.shard_map`` with ``axis_names`` (the manual
-    subset) and ``check_vma``; jax 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` with the complementary
-    ``auto`` set and ``check_rep``. Call sites use the new-style kwargs.
-    """
-    if hasattr(jax, "shard_map"):
-        import inspect
-        sig = inspect.signature(jax.shard_map).parameters
-        kw = {}
-        if "check_vma" in sig:
-            kw["check_vma"] = check_vma
-        elif "check_rep" in sig:       # mid-band: top-level API, old kwarg
-            kw["check_rep"] = check_vma
-        if axis_names is not None:
-            if "axis_names" in sig:
-                kw["axis_names"] = set(axis_names)
-            elif "auto" in sig:
-                kw["auto"] = frozenset(mesh.axis_names) \
-                    - frozenset(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {"check_rep": check_vma}
-    if axis_names is not None:
-        kw["auto"] = frozenset(mesh.axis_names) - frozenset(axis_names)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
 
 
 @contextlib.contextmanager
@@ -124,9 +91,7 @@ def station_mesh(n_stations: int | None = None, *, devices=None,
       so no device holds an empty shard.
 
     The hot path runs **fully manual** over this axis with zero
-    cross-station collectives, so the probe never needs to check for
-    partial-manual ``shard_map`` support (the jaxlib-0.4.x scan/gather
-    limitation only bites partial-manual regions).
+    cross-station collectives.
     """
     devs = list(devices) if devices is not None else jax.devices()
     nd = len(devs)
@@ -162,15 +127,11 @@ def padded_pool_width(n_stations: int, mesh: Mesh | None, *,
     return -(-int(n_stations) // d) * d
 
 
-def current_mesh() -> Mesh | None:
-    """The mesh installed by a ``with mesh:`` context, or None."""
-    try:
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and not m.empty:
-            return m
-    except Exception:
-        pass
-    return None
+def current_mesh() -> AbstractMesh | None:
+    """The mesh installed by ``jax.set_mesh`` (readable inside ``jit``
+    too), or None."""
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def axis_size(name: str) -> int:

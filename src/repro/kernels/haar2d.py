@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 
 def _kernel(img_ref, th_ref, tw_ref, out_ref):
     x = img_ref[...]  # (bn, H, W)
@@ -20,17 +22,17 @@ def _kernel(img_ref, th_ref, tw_ref, out_ref):
     tw = tw_ref[...]  # (W, W)
     # rows: y[n, h, v] = sum_w x[n, h, w] * tw[v, w]
     y = jax.lax.dot_general(
-        x, tw, (((2,), (1,)), ((), ())),
+        x, tw, (((2,), (1,)), ((), ())), precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32)  # (bn, H, V)
     # cols: z[n, u, v] = sum_h th[u, h] * y[n, h, v]
     z = jax.lax.dot_general(
-        y, th, (((1,), (1,)), ((), ())),
+        y, th, (((1,), (1,)), ((), ())), precision=MATMUL_PRECISION,
         preferred_element_type=jnp.float32)  # (bn, V, U) -> transpose
     out_ref[...] = jnp.swapaxes(z, 1, 2).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def haar2d(imgs: jax.Array, th: jax.Array, tw: jax.Array, *, bn: int = 128,
+def haar2d(imgs: jax.Array, th: jax.Array, tw: jax.Array, *, bn: int = 32,
            interpret: bool = False) -> jax.Array:
     """imgs: (N, H, W) float; th: (H, H); tw: (W, W). N % bn == 0."""
     n, h, w = imgs.shape

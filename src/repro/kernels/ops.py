@@ -2,8 +2,9 @@
 
 Each wrapper:
   * pads inputs to kernel-friendly block multiples and un-pads outputs,
-  * selects interpret mode automatically off-TPU (kernels VALIDATE on CPU
-    via interpret=True; TPU is the compile target),
+  * compiles the kernel on TPU and runs it in interpret mode on the CPU
+    (kernels VALIDATE on CPU via interpret=True; TPU is the compile
+    target); any other platform is an error, never a silent interpreter,
   * falls back to the pure-jnp oracle when ``use_pallas=False`` (the default
     for distributed dry-run lowering, where XLA-partitionable HLO is wanted).
 """
@@ -26,7 +27,13 @@ from repro.utils import round_up
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise NotImplementedError(
+            f"Pallas kernels compile for tpu and interpret on cpu; "
+            f"no path for platform {backend!r} (use_pallas=False runs the "
+            f"jnp oracles)")
+    return backend == "cpu"
 
 
 def _pad_axis(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
@@ -43,7 +50,7 @@ def _pad_axis(x: jax.Array, axis: int, mult: int, value=0) -> jax.Array:
 
 
 def minmax_hash(fp: jax.Array, mappings: jax.Array, *, use_pallas: bool = True,
-                bn: int = 16, bd: int = 256, bh: int = 256):
+                bn: int = 32, bd: int = 128, bh: int = 256):
     """(N, D) fingerprints × (D, H) mappings -> (mins, maxs), each (N, H)."""
     if not use_pallas:
         return _ref.minmax_hash(fp.astype(bool), mappings)
@@ -52,7 +59,7 @@ def minmax_hash(fp: jax.Array, mappings: jax.Array, *, use_pallas: bool = True,
     bn = min(bn, round_up(n, 8))
     bd = min(bd, round_up(d, 128))
     bh = min(bh, round_up(h, 128))
-    fp_p = _pad_axis(_pad_axis(fp.astype(jnp.int8), 0, bn), 1, bd)
+    fp_p = _pad_axis(_pad_axis(fp.astype(jnp.int32), 0, bn), 1, bd)
     mp_p = _pad_axis(_pad_axis(mappings, 0, bd), 1, bh, value=0)
     mins, maxs = _mm.minmax_hash(fp_p, mp_p, bn=bn, bd=bd, bh=bh,
                                  interpret=_interpret())
@@ -60,8 +67,8 @@ def minmax_hash(fp: jax.Array, mappings: jax.Array, *, use_pallas: bool = True,
 
 
 def minmax_sig_buckets(fp: jax.Array, mappings: jax.Array, salts: jax.Array,
-                       *, use_minmax: bool, n_buckets: int, bn: int = 16,
-                       bd: int = 256, bt: int = 32):
+                       *, use_minmax: bool, n_buckets: int, bn: int = 32,
+                       bd: int = 128, bt: int = 128):
     """(N, D) fingerprints × (D, T*f) mappings → per-table (signatures,
     bucket ids), each (N, T) — the Min-Max kernel with the signature fold
     + bucket addressing fused into its epilogue.
@@ -75,11 +82,14 @@ def minmax_sig_buckets(fp: jax.Array, mappings: jax.Array, salts: jax.Array,
     f = mappings.shape[1] // t
     bn = min(bn, round_up(n, 8))
     bd = min(bd, round_up(d, 128))
-    bt = min(bt, round_up(t, 8))
-    fp_p = _pad_axis(_pad_axis(fp.astype(jnp.int8), 0, bn), 1, bd)
-    # padding H to a multiple of bt*f pads whole tables (func-fastest
-    # layout), which the final [:t] slice drops again
-    mp_p = _pad_axis(_pad_axis(mappings, 0, bd), 1, bt * f, value=0)
+    tp = round_up(t, bt)
+    fp_p = _pad_axis(_pad_axis(fp.astype(jnp.int32), 0, bn), 1, bd)
+    # func-fastest (D, T*f) → pad to whole table tiles → function-major
+    # inside each tile of bt tables (the kernel's fold slices); the pad
+    # tables are dropped again by the final [:t] slice
+    mp = _pad_axis(mappings.reshape(d, t, f), 1, tp)
+    mp = mp.reshape(d, tp // bt, bt, f).transpose(0, 1, 3, 2)
+    mp_p = _pad_axis(mp.reshape(d, tp * f), 0, bd)
     salt_p = _pad_axis(salts.reshape(1, -1).astype(jnp.uint32), 1, bt)
     sig, bkt = _mm.minmax_sig_buckets(
         fp_p, mp_p, salt_p, f=f, use_minmax=use_minmax, n_buckets=n_buckets,
@@ -87,7 +97,7 @@ def minmax_sig_buckets(fp: jax.Array, mappings: jax.Array, salts: jax.Array,
     return sig[:n, :t], bkt[:n, :t]
 
 
-def haar2d(imgs: jax.Array, *, use_pallas: bool = True, bn: int = 128):
+def haar2d(imgs: jax.Array, *, use_pallas: bool = True, bn: int = 32):
     """Standard-decomposition 2-D Haar transform of (N, H, W) images."""
     if not use_pallas:
         return _ref.haar2d(imgs)
@@ -125,11 +135,10 @@ def jaccard_popcount(a: jax.Array, b: jax.Array, *, use_pallas: bool = True,
     if not use_pallas:
         return _ref.jaccard_popcount(a, b)
     p, w = a.shape
-    bp = min(bp, round_up(p, 8))
-    a_p = _pad_axis(a, 0, bp)
-    b_p = _pad_axis(b, 0, bp)
-    out = _jac.jaccard_popcount(a_p, b_p, bp=bp, interpret=_interpret())
-    return out[:p]
+    bp = min(bp, round_up(p, 128))
+    counts = _jac.jaccard_counts(_pad_axis(a, 0, bp), _pad_axis(b, 0, bp),
+                                 bp=bp, interpret=_interpret())
+    return _ref.jaccard_from_counts(counts[0, :p], counts[1, :p])
 
 
 def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,

@@ -12,13 +12,25 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+# Precision of the fingerprint chain's f32 matmuls (STFT, band pooling,
+# Haar — oracles and kernels alike). The TPU's default f32 matmul is one
+# bf16 pass, which perturbs spectra enough for the top-K binarization to
+# flip fingerprint bits against the CPU's f32 result; HIGHEST computes in
+# f32 on every backend (a no-op on the CPU, so CPU results are unchanged).
+MATMUL_PRECISION = jax.lax.Precision.HIGHEST
+
 # ---------------------------------------------------------------------------
 # Min-Max hash (paper §6.2, Algorithm 1) — the LSH hot spot
 # ---------------------------------------------------------------------------
 
 
+@jax.jit
 def minmax_hash(fp: jax.Array, mappings: jax.Array) -> tuple[jax.Array, jax.Array]:
     """Min and max of hash mappings over the non-zero dims of each fingerprint.
+
+    Jitted so that the (N, D, H) masked broadcast is fused into the two
+    reductions even when called eagerly (``lsh.search``): run op by op it
+    would be materialised, tens of GB at the paper's widths.
 
     Args:
       fp: (N, D) boolean fingerprints.
@@ -67,7 +79,8 @@ def haar2d(imgs: jax.Array) -> jax.Array:
     h, w = imgs.shape[-2:]
     th = jnp.asarray(haar_matrix(h), imgs.dtype)
     tw = jnp.asarray(haar_matrix(w), imgs.dtype)
-    return jnp.einsum("ij,...jk,lk->...il", th, imgs, tw)
+    return jnp.einsum("ij,...jk,lk->...il", th, imgs, tw,
+                      precision=MATMUL_PRECISION)
 
 
 # ---------------------------------------------------------------------------
@@ -90,8 +103,8 @@ def stft_mag(frames: jax.Array, window: jax.Array, dft_r: jax.Array,
     frames: (N, L); window: (L,); dft_r/dft_i: (L, K). Returns (N, K) power.
     """
     xw = frames * window[None, :]
-    re = xw @ dft_r
-    im = xw @ dft_i
+    re = jnp.matmul(xw, dft_r, precision=MATMUL_PRECISION)
+    im = jnp.matmul(xw, dft_i, precision=MATMUL_PRECISION)
     return re * re + im * im
 
 
@@ -108,6 +121,13 @@ def jaccard_popcount(a: jax.Array, b: jax.Array) -> jax.Array:
     """
     inter = jax.lax.population_count(a & b).astype(jnp.int32).sum(axis=-1)
     union = jax.lax.population_count(a | b).astype(jnp.int32).sum(axis=-1)
+    return jaccard_from_counts(inter, union)
+
+
+def jaccard_from_counts(inter: jax.Array, union: jax.Array) -> jax.Array:
+    """Intersection / union popcounts → Jaccard (float32; empty unions
+    give 0). Shared by the oracle and the Pallas wrapper so both divide
+    with the same lowering."""
     return jnp.where(union > 0, inter / jnp.maximum(union, 1), 0.0).astype(
         jnp.float32
     )

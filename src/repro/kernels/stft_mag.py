@@ -13,12 +13,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.ref import MATMUL_PRECISION
+
 
 def _kernel(fr_ref, win_ref, dr_ref, di_ref, out_ref):
     x = fr_ref[...] * win_ref[...]  # (bf, L) * (1, L)
     re = jax.lax.dot_general(x, dr_ref[...], (((1,), (0,)), ((), ())),
+                             precision=MATMUL_PRECISION,
                              preferred_element_type=jnp.float32)
     im = jax.lax.dot_general(x, di_ref[...], (((1,), (0,)), ((), ())),
+                             precision=MATMUL_PRECISION,
                              preferred_element_type=jnp.float32)
     out_ref[...] = (re * re + im * im).astype(out_ref.dtype)
 

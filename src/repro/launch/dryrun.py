@@ -60,7 +60,7 @@ def _rules_to_shardings(rules, shapes_tree, mesh):
         if isinstance(rule, tuple):
             tok = _UNEVEN.set(False)
             try:
-                with mesh:
+                with jax.set_mesh(mesh):
                     spec = dist.sanitize_spec(shp.shape, rule)
             finally:
                 _UNEVEN.reset(tok)
@@ -77,7 +77,7 @@ def _batch_shardings(batch_specs, mesh):
 
     def one(sds):
         spec = (ba,) + (None,) * (len(sds.shape) - 1)
-        with mesh:
+        with jax.set_mesh(mesh):
             s = dist.sanitize_spec(sds.shape, spec)
         return NamedSharding(mesh, s if s is not None else P())
 
@@ -117,7 +117,7 @@ def lower_lm_cell(arch: str, shape_name: str, mesh, attn_impl: str,
             dp *= mesh.shape[a]
 
     p_rules = param_sharding_rules(cfg)
-    with mesh:
+    with jax.set_mesh(mesh):
         if spec.kind == "train":
             n_mb = microbatches or pick_microbatches(cfg, spec.global_batch,
                                                      dp)
@@ -214,7 +214,7 @@ def lower_detect_cell(shape_name: str, mesh, use_shard_map: bool = True,
     else:  # SPMD-partitioner baseline (kept for §Perf comparison)
         step = jax.vmap(functools.partial(detect_step, cfg=dcfg, **knobs),
                         in_axes=(0, None, None))
-    with mesh:
+    with jax.set_mesh(mesh):
         jitted = jax.jit(step, in_shardings=(wf_sh, stat_sh, stat_sh))
         lowered = jitted.lower(specs["waveforms"], specs["med"],
                                specs["mad"])

@@ -108,6 +108,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.fast_seismic import smoke_config, stream_smoke_config
 from repro.core import fingerprint as fp_mod
 from repro.core import lsh as lsh_mod
@@ -567,6 +568,7 @@ def main(argv=None):
                          "relative magnitude (defaults "
                          "--filter-window-fp 64 so alerts emit live)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.locate:
         from repro.configs.fast_seismic import located_smoke_config

@@ -440,7 +440,7 @@ def moe_block(params: dict, x: jax.Array, cfg: ModelConfig):
             return y, aux
 
         spec_h = P(ba if ba else None, None)
-        out = dist.shard_map(
+        out = jax.shard_map(
             per_shard, mesh=mesh,
             in_specs=(spec_h, P(None, None), P("model", None, None),
                       P("model", None, None), P("model", None, None)),

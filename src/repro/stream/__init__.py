@@ -316,10 +316,9 @@ chip. Three properties make this the cheap kind of distribution:
 
 * **zero in-region collectives**: stations are independent until the
   host-side association tail, so ``pool_step_*_sharded`` run the same
-  per-station ``core`` under ``dist.shard_map`` **fully manual** over
+  per-station ``core`` under ``jax.shard_map`` **fully manual** over
   the ``stations`` axis — no cross-device communication inside the
-  traced program, which also sidesteps the jaxlib-0.4.x partial-manual
-  scan/gather limitation (only partial-manual regions hit it). Donation
+  traced program. Donation
   and the one-dispatch-per-block invariant carry over per shard; the
   pair/QC outputs come back through the same single ``device_get``.
 * **capability probe, vmap fallback**: ``dist.station_mesh`` returns

@@ -1068,6 +1068,21 @@ class StationStream:
                                            maxlen=WALL_WINDOW))
 
 
+def _per_station_stats(med_mad, n_stations: int) -> list:
+    """Frozen statistics as one (med, mad) pair per station: ``med_mad``
+    is a shared pair of (n_coeff,) arrays, or (S, n_coeff) arrays with one
+    row per station (``core.detect.station_stats``), or None."""
+    if med_mad is None:
+        return [None] * n_stations
+    med, mad = med_mad
+    if np.ndim(med) == 1:
+        return [(med, mad)] * n_stations
+    if len(med) != n_stations or len(mad) != n_stations:
+        raise ValueError(f"per-station med_mad needs {n_stations} rows, "
+                         f"got {len(med)} and {len(mad)}")
+    return [(med[i], mad[i]) for i in range(n_stations)]
+
+
 class StreamingDetector:
     """Multi-station streaming FAST: push chunks, read detections.
 
@@ -1115,10 +1130,10 @@ class StreamingDetector:
         self.pool_pad = dist.padded_pool_width(n_stations,
                                                self.mesh) - n_stations
         self.telemetry = StreamTelemetry(n_stations)
-        self.stations = [StationStream(cfg, self.scfg, med_mad=med_mad,
+        self.stations = [StationStream(cfg, self.scfg, med_mad=mm,
                                        external=self.pooled,
                                        telemetry=self.telemetry)
-                         for _ in range(n_stations)]
+                         for mm in _per_station_stats(med_mad, n_stations)]
         self.pstate: fused_mod.FusedState | None = None
         self._halo_ok = False
         self.mappings = self.stations[0].mappings

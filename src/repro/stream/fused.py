@@ -247,13 +247,10 @@ def pool_step_block(state: FusedState, blocks: jax.Array,
 # ---------------------------------------------------------------------------
 #
 # The leading S axis of every FusedState leaf is split over the mesh's
-# ``stations`` axis via the version-portable ``dist.shard_map`` wrapper;
-# inside the region each device runs the identical vmapped per-station
-# core over its own S/D rows. The hot path has **zero** cross-station
-# communication (association is a host tail), so the region is fully
-# manual and needs no collectives — which is exactly what sidesteps the
-# jaxlib-0.4.x partial-manual shard_map scan/gather limitation the
-# ROADMAP names as the blocker: only partial-manual regions hit it.
+# ``stations`` axis via ``jax.shard_map``; inside the region each device
+# runs the identical vmapped per-station core over its own S/D rows. The
+# hot path has **zero** cross-station communication (association is a
+# host tail), so the region is fully manual and needs no collectives.
 #
 # ``mappings`` and ``base_id`` are replicated (every station hashes with
 # the same tables and ingests the same block cadence); all outputs carry
@@ -305,9 +302,9 @@ def _sharded_entry(mesh, advance: bool, statics: tuple):
                               med=state.med, mad=state.mad), pairs, qc
 
         in_specs = (P(axis), P(axis), P(), P(), P(axis))
-    sharded = dist.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=(P(axis), P(axis), P(axis)),
-                             axis_names=(axis,))
+    sharded = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                            out_specs=(P(axis), P(axis), P(axis)),
+                            axis_names={axis})
     fn = jax.jit(sharded, donate_argnums=(0,))
     _SHARDED_ENTRIES[key] = fn
     return fn

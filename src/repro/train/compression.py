@@ -91,7 +91,7 @@ def pod_compressed_value_and_grad(loss_fn, mesh, batch_spec_prefix=P("pod")):
             P(),
             jax.tree.map(lambda p: P() if _exempt(p) else P("pod"), params),
             jax.tree.map(lambda p: P() if _exempt(p) else P("pod"), params))
-        loss, q, s = dist.shard_map(
+        loss, q, s = jax.shard_map(
             per_pod, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
             axis_names={"pod"}, check_vma=False)(params, batch)
 

@@ -50,7 +50,9 @@ def fold_hashes(h: jax.Array, axis: int = -1) -> jax.Array:
     def body(carry, x):
         return hash_combine(carry, x), None
 
-    init = jnp.zeros(h.shape[1:], jnp.uint32)
+    # zeros_like keeps the input's type, so inside a shard_map region the
+    # carry is varying over the same mesh axes as ``h`` (check_vma)
+    init = jnp.zeros_like(h[0], jnp.uint32)
     out, _ = jax.lax.scan(body, init, h)
     return out
 

@@ -34,7 +34,7 @@ mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
 def loss_fn(p, b):
     return lm_loss(p, b, CFG)[0]
 
-with mesh:
+with jax.set_mesh(mesh):
     batch_s = jax.device_put(batch, NamedSharding(mesh, P(("pod", "data"))))
     # exact reference
     loss_ref, grads_ref = jax.jit(jax.value_and_grad(loss_fn))(params,

@@ -43,7 +43,7 @@ def to_sh(rule_tree, tree):
         return {k: walk(r[k], t[k]) for k in r}
     return walk(rule_tree, tree)
 
-with mesh:
+with jax.set_mesh(mesh):
     psh = to_sh(rules, params)
     params_s = jax.device_put(params, psh)
     batch_s = jax.device_put(batch, NamedSharding(mesh, P(("data",))))
@@ -80,7 +80,7 @@ x = jnp.asarray(rng.standard_normal((4, 16, 64)), jnp.float32)
 y_dense, aux_dense = L.moe_block(lp["moe"], x, CFG)   # no mesh → dense path
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
-with mesh:
+with jax.set_mesh(mesh):
     moe_sh = {k: NamedSharding(mesh, P("model", None, None))
               if k in ("wg", "wu", "wd") else NamedSharding(mesh, P())
               for k in lp["moe"]}
@@ -121,7 +121,7 @@ for t in toks[:-1]:
 logits_ref, _ = decode_step(params, c, toks[-1], CFG)
 
 mesh = jax.make_mesh((2, 4), ("data", "model"))
-with mesh:
+with jax.set_mesh(mesh):
     rules = cache_sharding_rules(CFG)
     def sh(rule, t):
         spec = dist.sanitize_spec(t.shape, rule)

@@ -136,6 +136,20 @@ def test_jaccard_matches_ref(rng, p, w):
                                atol=1e-6)
 
 
+def test_kernels_compile_on_tpu_interpret_on_cpu_else_raise(monkeypatch):
+    """The wrappers interpret on the CPU, compile on the TPU, and refuse
+    any other platform instead of quietly interpreting there."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "cpu")
+    assert ops._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert not ops._interpret()
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    a = jnp.zeros((4, 2), jnp.uint32)
+    with pytest.raises(NotImplementedError, match="gpu"):
+        ops.jaccard_popcount(a, a)
+    ops.jaccard_popcount(a, a, use_pallas=False)     # the oracle still runs
+
+
 def test_jaccard_identical_and_disjoint():
     a = np.asarray([[0b1010, 0], [0, 0b1]], np.uint32)
     b = np.asarray([[0b0101, 0], [0, 0b1]], np.uint32)

@@ -82,3 +82,25 @@ def test_synth_ground_truth_arrivals():
         w1 = ds.waveforms[0, a1:a1 + n]
         c = np.corrcoef(w0, w1)[0, 1]
         assert c > 0.3, c
+
+
+def test_compile_cache_placement(monkeypatch, tmp_path):
+    """The persistent compile cache is $JAX_COMPILATION_CACHE_DIR when set
+    (left for JAX to read) and otherwise the checkout's fixed
+    ``.jax_cache/``."""
+    import pathlib
+
+    import jax
+    from repro.compile_cache import enable_compile_cache
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = pathlib.Path(__file__).resolve().parents[1]
+        assert enable_compile_cache() == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == str(root
+                                                           / ".jax_cache")
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -182,6 +182,22 @@ print("DIGEST", *digest(det))
         assert out.splitlines()[-1] == ref, (devices, out, ref)
 
 
+def test_bench_sharded_grid_refuses_accelerator(monkeypatch):
+    """The sharded grid times forced CPU host devices in child
+    interpreters; under an accelerator it raises instead of quietly
+    measuring the host."""
+    import sys
+
+    import jax
+    root = str(pathlib.Path(__file__).parent.parent)
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks import bench_e2e
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="cannot measure a tpu"):
+        bench_e2e.sharded_pool_points(quick=True)
+
+
 @pytest.mark.slow
 def test_bench_sharded_grid_schema(tmp_path, monkeypatch):
     """``make bench-sharded`` contract: the quick grid runs its forced-

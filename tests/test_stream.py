@@ -439,6 +439,36 @@ def test_streaming_golden_pair_parity():
     assert det_cmp.telemetry.drop_breakdown()["overflow_pairs"] == 0
 
 
+def test_streaming_with_station_stats_equals_backfill():
+    """Given the offline per-station statistics (``station_stats``, one
+    row per station), the streaming pool and the batch replay run the
+    same binarization per station: their per-station pair sets are
+    identical and non-empty. Mismatched rows are rejected."""
+    from repro.core.detect import detect_events, station_stats
+    cfg, scfg = smoke_config(), stream_compact_smoke_config()
+    ds = make_dataset(SynthConfig(duration_s=600.0, n_stations=2,
+                                  n_sources=2, events_per_source=5,
+                                  event_snr=3.0, seed=3))
+    med, mad = station_stats(ds.waveforms, cfg.fingerprint)
+    assert med.shape == mad.shape == (2, cfg.fingerprint.n_coeff)
+    det = StreamingDetector(cfg, scfg, n_stations=2, med_mad=(med, mad))
+    for chunk in np.array_split(ds.waveforms, 10, axis=1):
+        det.push(chunk)
+    _, _, _, stats = detect_events(ds.waveforms, cfg, scfg=scfg,
+                                   keep_pairs=True)
+
+    def as_set(p):
+        v = np.asarray(p.valid)
+        return set(zip(np.asarray(p.idx1)[v].tolist(),
+                       np.asarray(p.idx2)[v].tolist()))
+
+    streamed = [as_set(st.finalize()[1]) for st in det.stations]
+    assert streamed == [as_set(p) for p in stats["_station_pairs"]]
+    assert all(streamed)
+    with pytest.raises(ValueError, match="per-station med_mad"):
+        StreamingDetector(cfg, scfg, n_stations=3, med_mad=(med, mad))
+
+
 # ---------------------------------------------------------------------------
 # emission epilogue (ISSUE 8): compaction, overflow, verify ring
 # ---------------------------------------------------------------------------
