@@ -1,0 +1,11 @@
+"""Host-to-device put of the step's inputs, in ms per execution of the
+step: the program's ``put`` span over its ``fused_step`` count. None
+where the program has no such span."""
+
+
+def read(ctx):
+    sp = ctx["spans"]
+    n = sp.get("fused_step", (0, 0.0))[0]
+    if "put" not in sp or n == 0:
+        return None
+    return sp["put"][1] / n * 1e3

@@ -74,8 +74,12 @@ Live health surface (ISSUE 6):
                           ``--metrics-every`` is set, and always once
                           after ingest (a bare ``--metrics-file`` does a
                           final write instead of silently nothing).
-  ``--trace-jsonl P``     append structured JSONL spans of the ingest
-                          path to ``P``.
+  ``--trace-jsonl P``     append the span tree of every push (ingest,
+                          dedup, the step's put/dispatch/wait/pull, host
+                          tail, detections; ids, parents and realtime
+                          ns, see ``repro.obsv.spans``) as JSONL to
+                          ``P``, buffered in memory and flushed at every
+                          ``--metrics-every`` heartbeat and at exit.
   ``--dirty``             ingest the fault-injected scenario stream
                           through the quality-hardened config.
   ``--locate``            located alert rows (ISSUE 9): the synthetic
@@ -770,6 +774,7 @@ def main(argv=None):
         stats["located"] = located_summary
     if args.metrics_every:
         stats["metrics"] = det.metrics_snapshot()
+    det.telemetry.tracer.close()
     print("RESULT " + json.dumps(stats))
     return stats
 

@@ -11,10 +11,13 @@ detector, the batch replay driver, and the serving loop:
     (``render_prometheus``).
 
 ``spans``
-    :class:`SpanTracer` — lightweight nested wall-clock spans
-    (ingest → fused step → host tail → merge/cluster → associate)
-    that always accumulate per-name totals and optionally emit a
-    structured JSONL event log; plus an optional ``jax.profiler``
+    :class:`SpanTracer` — lightweight nested wall-clock spans (the
+    streaming detector's tree per push: chunk → ingest/dedup →
+    fused_step/put/dispatch/wait/pull → host_tail → detections) that
+    always accumulate per-name totals, appear in a running
+    ``jax.profiler`` trace as host annotations of the same names, and
+    optionally buffer a structured JSONL log whose records carry ids,
+    parents and realtime ns; plus an optional ``jax.profiler``
     trace-dump hook for when a heartbeat anomaly needs an XLA-level
     view.
 

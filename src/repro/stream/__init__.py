@@ -158,13 +158,26 @@ pair stream — never an extra dispatch, never a change to detections.
   O(1) memory per series; snapshots/restores alongside the detector so a
   restarted service resumes its counters. Rendered as Prometheus text
   exposition (``repro.obsv.metrics.render_prometheus``).
-* **span tracing** (``repro.obsv.spans.SpanTracer``): nested wall-clock
-  spans over ingest → fused_step → host_tail (and the batch replay's
-  stages — ``core.detect.StageTimes`` is *derived* from the span totals),
-  optional structured JSONL emission and a ``jax.profiler`` trace hook.
+* **span tracing** (``repro.obsv.spans.SpanTracer``): one span tree per
+  push — ``chunk`` → ``ingest`` (ring framing, block staging; ``dedup``,
+  the duplicate guard, inside it), ``fused_step`` (``put``,
+  ``dispatch``, ``wait``, ``pull``), ``host_tail``, ``detections`` —
+  each layer timed where its work happens, each span wrapping the loop
+  over stations. Records carry ``id``/``parent``/``trace`` and
+  ``CLOCK_REALTIME`` ``start_ns``/``end_ns``, buffered in memory and
+  written as JSONL on flush; every span is also a
+  ``jax.profiler.TraceAnnotation``, so a profile of the running service
+  shows the host stage beside the device's operations on one clock. The
+  registry histograms and the watchdog read the span durations; the
+  batch replay's ``core.detect.StageTimes`` is *derived* from the span
+  totals. On the device, the step's stages run under
+  ``jax.named_scope`` (``fingerprint``, ``hash``, ``expire``,
+  ``dup_guard``, ``insert``, ``query``, ``limit``, ``compact``,
+  ``verify``), carried in every operation's metadata.
 * **watchdog**: the training loop's ``train/watchdog.StepWatchdog``
-  wraps every streaming dispatch — one step per pooled dispatch —
-  flagging stragglers into ``straggler_steps_total``.
+  observes every streaming dispatch — one step per pooled dispatch, the
+  ``fused_step`` span's duration — flagging stragglers into
+  ``straggler_steps_total``.
 * **health surface**: ``StreamingDetector.metrics_snapshot()`` is the
   single structured view (schema ``stream-metrics/v1``) consumed by
   ``bench_stream`` / ``bench_e2e`` artifacts, the examples, and

@@ -586,18 +586,38 @@ class StationStream:
         assert not self.external, \
             "pooled stations are pushed through their StreamingDetector"
         self.telemetry.start()
-        t0 = time.perf_counter()
-        emitted = 0
+        with self.telemetry.tracer.span("chunk",
+                                        station=self._pool_idx) as sp:
+            emitted = self._push(chunk, offset)
+        self._record_chunk(int(np.asarray(chunk).size), sp.dur_s)
+        return emitted
+
+    def _push(self, chunk: np.ndarray, offset: int | None) -> int:
+        """The children of one push's ``chunk`` span: ``ingest`` (ring
+        framing and the duplicate guard), then each ready block through
+        the step."""
         with self.telemetry.tracer.span("ingest", station=self._pool_idx):
-            for base_id, block, mask in self.ring.push(chunk, offset):
-                emitted += self._ingest_block(base_id, block, mask)
-        n_samples = int(np.asarray(chunk).size)
+            ready = self._dedup(self.ring.push(chunk, offset))
+        return sum(self._ingest_block(*b) for b in ready)
+
+    def _record_chunk(self, n_samples: int, wall: float) -> None:
         self.stats.chunks += 1
         self.stats.samples += n_samples
-        wall = time.perf_counter() - t0
         self.stats.record_wall(wall)
         self.telemetry.record_chunk(self._pool_idx, wall, n_samples)
-        return emitted
+
+    def _dedup(self, ready: list, tail: bool = False) -> list:
+        """``ready`` (base_id, block, mask) blocks with the duplicate
+        guard's flags merged into their masks, under the ``dedup`` span;
+        ``tail`` marks a flush tail, which ends at ``ring.next_fp``."""
+        end_id = self.ring.next_fp if tail else None
+        with self.telemetry.tracer.span("dedup",
+                                        station=self._pool_idx) as sp:
+            before = self.qc["duplicate_fingerprints"]
+            out = [(b, block, self._flag_duplicates(b, block, mask, end_id))
+                   for b, block, mask in ready]
+            sp.set(flagged=self.qc["duplicate_fingerprints"] - before)
+        return out
 
     def _flag_duplicates(self, base_id: int, block: np.ndarray,
                          mask: np.ndarray | None,
@@ -670,7 +690,8 @@ class StationStream:
 
     def _ingest_block(self, base_id: int, block: np.ndarray,
                       mask: np.ndarray | None = None) -> int:
-        mask = self._flag_duplicates(base_id, block, mask)
+        """One block whose duplicates ``_dedup`` has flagged: into the
+        warm-up reservoir, or through the step."""
         if not self.stats_frozen:
             coeffs = block_coeffs(jnp.asarray(block), self.cfg.fingerprint)
             rows = np.asarray(coeffs)
@@ -738,53 +759,61 @@ class StationStream:
         ver = self.scfg.verify_code
         mj = self.scfg.verify_min_jaccard
         n = self.scfg.block_fingerprints
-        vmask = (np.ones(n, bool) if valid is None
-                 else np.asarray(valid, bool))
         if n_adv is None:
             n_adv = n
-        wd = self.telemetry.watchdog
-        wd.step_start()
-        with self.telemetry.tracer.span("fused_step",
-                                        station=self._pool_idx):
-            if self.fused:
-                if valid is None and self._halo_ok:
-                    adv = np.asarray(block, np.float32)[-self.ring.advance:]
+        st = self._pool_idx
+        advance = self.fused and valid is None and self._halo_ok
+        with self.telemetry.tracer.span("ingest", station=st):
+            vmask = (np.ones(n, bool) if valid is None
+                     else np.asarray(valid, bool))
+            if advance:
+                host = (np.asarray(block, np.float32)[-self.ring.advance:],)
+            elif self.fused or coeffs is None:
+                host = (block, vmask)
+            else:
+                host = (vmask,)
+        with self.telemetry.tracer.span("fused_step", station=st) as step:
+            with self.telemetry.tracer.span("put", station=st) as sp:
+                dev = [jnp.asarray(x) for x in host]
+                bid = jnp.int32(base_id)
+                sp.set(bytes=sum(x.nbytes for x in host))
+            with self.telemetry.tracer.span("dispatch", station=st):
+                if advance:
                     self.fstate, pairs, qc = fused_mod.step_advance(
-                        self.fstate, jnp.asarray(adv), self.mappings,
-                        jnp.int32(base_id), fcfg, lcfg, window, sat, dup,
-                        occ, ctr, mp, ver, mj)
-                else:
-                    self.fstate, pairs, qc = fused_mod.step_block(
-                        self.fstate, jnp.asarray(block), self.mappings,
-                        jnp.int32(base_id), jnp.asarray(vmask), fcfg, lcfg,
+                        self.fstate, dev[0], self.mappings, bid, fcfg, lcfg,
                         window, sat, dup, occ, ctr, mp, ver, mj)
+                elif self.fused:
+                    self.fstate, pairs, qc = fused_mod.step_block(
+                        self.fstate, dev[0], self.mappings, bid, dev[1],
+                        fcfg, lcfg, window, sat, dup, occ, ctr, mp, ver, mj)
                     # a zero-padded tail leaves the device halo dirty and
                     # the next block must re-seed through step_block; a
                     # fully framed (gap-masked) block primes it clean
                     self._halo_ok = valid is None or primed
-            else:
-                if coeffs is None:
-                    coeffs = block_coeffs(jnp.asarray(block), fcfg)
-                med, mad = self._med_mad
-                self._state, pairs, qc = stream_step(
-                    self._state, coeffs, med, mad, self.mappings,
-                    jnp.int32(base_id), jnp.asarray(vmask), fcfg, lcfg,
-                    window, sat, dup, occ, ctr, mp, ver, mj)
+                else:
+                    if coeffs is None:
+                        coeffs = block_coeffs(dev[0], fcfg)
+                    med, mad = self._med_mad
+                    self._state, pairs, qc = stream_step(
+                        self._state, coeffs, med, mad, self.mappings, bid,
+                        dev[-1], fcfg, lcfg, window, sat, dup, occ, ctr, mp,
+                        ver, mj)
             # one device_get over the whole step output (ISSUE 8: a
-            # single transfer+sync, not four) blocks on the dispatch, so
-            # the watchdog step (and the fused-wall histogram) covers
-            # device time incl. sync. With compaction on, the pulled
-            # pair arrays are O(max_pairs), not O(t·N·cap).
-            pairs_np, qc = jax.device_get(
-                ((pairs.idx1, pairs.idx2, pairs.sim, pairs.valid), qc))
-        self.telemetry.record_fused_wall(str(self._pool_idx), wd.step_end())
+            # single transfer, not four), after the wait for the device;
+            # with compaction on, the pulled pair arrays are
+            # O(max_pairs), not O(t·N·cap)
+            out = ((pairs.idx1, pairs.idx2, pairs.sim, pairs.valid), qc)
+            with self.telemetry.tracer.span("wait", station=st):
+                jax.block_until_ready(out)
+            with self.telemetry.tracer.span("pull", station=st) as sp:
+                pairs_np, qc = jax.device_get(out)
+                sp.set(bytes=sum(x.nbytes for x in pairs_np) + qc.nbytes)
+        self.telemetry.record_fused_wall(str(st), step.dur_s)
         self._absorb_qc(qc, n_adv - int(vmask[:n_adv].sum()))
-        t_host = time.perf_counter()
-        with self.telemetry.tracer.span("host_tail",
-                                        station=self._pool_idx):
+        with self.telemetry.tracer.span("host_tail", station=st) as tail:
             m = self._consume(base_id, n_adv, int(vmask.sum()), pairs_np)
-        self.telemetry.record_host_tail(self._pool_idx,
-                                        time.perf_counter() - t_host)
+            tail.set(pairs=m)
+        self.telemetry.record_host_tail(st, tail.dur_s)
         return m
 
     def _consume(self, base_id: int, n_adv: int, n_valid: int,
@@ -829,16 +858,15 @@ class StationStream:
         if self.external:
             return 0                # the owning detector flushes the pool
         emitted = 0
-        ready = 0
-        for base_id, block, mask in self.ring.flush_ready():
-            ready += self._ingest_block(base_id, block, mask)
-        part = self.ring.flush_partial()
+        with self.telemetry.tracer.span("ingest", station=self._pool_idx):
+            blocks = self._dedup(self.ring.flush_ready())
+            part = self.ring.flush_partial()
+            if part is not None:
+                (part,) = self._dedup([part], tail=True)
+        ready = sum(self._ingest_block(*b) for b in blocks)
         part_coeffs = None
         if part is not None:
             base_id, block, mask = part
-            mask = self._flag_duplicates(base_id, block, mask,
-                                         end_id=self.ring.next_fp)
-            part = (base_id, block, mask)
             if not self.stats_frozen or not self.fused:
                 part_coeffs = block_coeffs(jnp.asarray(block),
                                            self.cfg.fingerprint)
@@ -1172,20 +1200,27 @@ class StreamingDetector:
             chunk = chunk[None, :]
         assert chunk.shape[0] == len(self.stations), \
             (chunk.shape, len(self.stations))
-        if self.locating:
-            pos = (self.stations[0].ring.frontier if offset is None
-                   else int(offset))
-            for i in range(chunk.shape[0]):
-                self._note_amps(i, pos, chunk[i])
-        if self.pooled:
-            emitted = self._pool_push(chunk, offset)
-        else:
-            emitted = sum(st.push(chunk[i], offset)
-                          for i, st in enumerate(self.stations))
-        if self.rolling and len(self.stations) >= 2:
-            new = self.poll_detections()
-            if new.shape[0]:
-                self.alerts.append(new)
+        self.telemetry.start()
+        with self.telemetry.tracer.span(
+                "chunk", stations=len(self.stations)) as sp:
+            if self.locating:
+                pos = (self.stations[0].ring.frontier if offset is None
+                       else int(offset))
+                for i in range(chunk.shape[0]):
+                    self._note_amps(i, pos, chunk[i])
+            if self.pooled:
+                emitted = self._pool_push(chunk, offset)
+            else:
+                emitted = sum(st._push(chunk[i], offset)
+                              for i, st in enumerate(self.stations))
+            if self.rolling and len(self.stations) >= 2:
+                with self.telemetry.tracer.span("detections"):
+                    new = self.poll_detections()
+                if new.shape[0]:
+                    self.alerts.append(new)
+        # the stations share the push, so each records its wall
+        for i, st in enumerate(self.stations):
+            st._record_chunk(int(chunk[i].size), sp.dur_s)
         self.serving_version += 1
         return emitted
 
@@ -1237,33 +1272,40 @@ class StreamingDetector:
 
     def _pool_push(self, chunk: np.ndarray, offset: int | None = None
                    ) -> int:
-        self.telemetry.start()
-        t0 = time.perf_counter()
-        per_st = [st.ring.push(chunk[i], offset)
-                  for i, st in enumerate(self.stations)]
-        emitted = 0
         with self.telemetry.tracer.span("ingest", station="pool"):
-            for k in range(len(per_st[0])):   # rings advance in lockstep
-                base_id = per_st[0][k][0]
-                blocks = np.stack([per_st[i][k][1]
-                                   for i in range(len(self.stations))])
-                masks = [per_st[i][k][2]
-                         for i in range(len(self.stations))]
-                emitted += self._pool_ingest_block(base_id, blocks, masks)
-        wall = time.perf_counter() - t0
-        for i, st in enumerate(self.stations):
-            st.stats.chunks += 1
-            st.stats.samples += int(chunk[i].size)
-            st.stats.record_wall(wall)  # stations share the dispatch
-            self.telemetry.record_chunk(i, wall, int(chunk[i].size))
-        return emitted
+            ready = self._pool_dedup(self._pool_blocks(
+                [st.ring.push(chunk[i], offset)
+                 for i, st in enumerate(self.stations)]))
+        return sum(self._pool_ingest_block(*b) for b in ready)
+
+    def _pool_blocks(self, per_st: list) -> list:
+        """The lockstep rings' ready blocks, each as (base_id, (S, block)
+        stack, per-station masks)."""
+        s = len(self.stations)
+        return [(per_st[0][k][0], np.stack([per_st[i][k][1]
+                                            for i in range(s)]),
+                 [per_st[i][k][2] for i in range(s)])
+                for k in range(len(per_st[0]))]
+
+    def _pool_dedup(self, ready: list, tail: bool = False) -> list:
+        """``StationStream._dedup`` over every station of each pooled
+        block, under one ``dedup`` span."""
+        with self.telemetry.tracer.span("dedup", station="pool") as sp:
+            before = sum(st.qc["duplicate_fingerprints"]
+                         for st in self.stations)
+            out = [(b, blocks, [st._flag_duplicates(
+                        b, blocks[i], masks[i],
+                        st.ring.next_fp if tail else None)
+                        for i, st in enumerate(self.stations)])
+                   for b, blocks, masks in ready]
+            sp.set(flagged=sum(st.qc["duplicate_fingerprints"]
+                               for st in self.stations) - before)
+        return out
 
     def _pool_ingest_block(self, base_id: int, blocks: np.ndarray,
-                           masks: list | None = None) -> int:
-        if masks is None:
-            masks = [None] * len(self.stations)
-        masks = [st._flag_duplicates(base_id, blocks[i], masks[i])
-                 for i, st in enumerate(self.stations)]
+                           masks: list) -> int:
+        """One pooled block whose duplicates ``_pool_dedup`` has
+        flagged: into the warm-up reservoirs, or through the step."""
         if self.pstate is None:
             coeffs = np.asarray(pool_block_coeffs(jnp.asarray(blocks),
                                                   self.cfg.fingerprint))
@@ -1320,86 +1362,90 @@ class StreamingDetector:
         clean = masks is None or all(m is None for m in masks)
         if n_adv is None:
             n_adv = n
-        wd = self.telemetry.watchdog
-        wd.step_start()
+        advance = clean and self._halo_ok and n_adv == n
+        with self.telemetry.tracer.span("ingest", station="pool"):
+            if advance:
+                vm = np.ones((s, n), bool)
+                host = (self._pad_rows(
+                    blocks[:, -self.stations[0].ring.advance:]),)
+            else:
+                vm = np.stack([
+                    np.ones(n, bool) if (masks is None or masks[i] is None)
+                    else np.asarray(masks[i], bool) for i in range(s)])
+                host = (self._pad_rows(blocks),
+                        self._pad_rows(vm, fill=False))
         # per-station host inputs go straight to their shard: under a
         # mesh, a plain jnp.asarray would land the whole array on device
         # 0 and pay a second device-0 → shards scatter inside dispatch
         put = (jnp.asarray if self.mesh is None else
                functools.partial(jax.device_put,
                                  device=dist.pool_sharding(self.mesh)))
-        with self.telemetry.tracer.span("fused_step", station="pool"):
-            if clean and self._halo_ok and n_adv == n:
-                adv = self._pad_rows(
-                    blocks[:, -self.stations[0].ring.advance:])
-                self.pstate, pairs, qc = fused_mod.pool_step_advance_sharded(
-                    self.pstate, put(adv), self._pool_mappings,
-                    jnp.int32(base_id), fcfg, lcfg, window, sat, dup, occ,
-                    ctr, mp, ver, mj, mesh=self.mesh)
-                vm = np.ones((s, n), bool)
-            else:
-                vm = np.stack([
-                    np.ones(n, bool) if (masks is None or masks[i] is None)
-                    else np.asarray(masks[i], bool) for i in range(s)])
-                self.pstate, pairs, qc = fused_mod.pool_step_block_sharded(
-                    self.pstate, put(self._pad_rows(blocks)),
-                    self._pool_mappings, jnp.int32(base_id),
-                    put(self._pad_rows(vm, fill=False)), fcfg,
-                    lcfg, window, sat, dup, occ, ctr, mp, ver, mj,
-                    mesh=self.mesh)
-                self._halo_ok = clean or primed
-            # one transfer + one sync for the whole pooled step output
-            (i1, i2, sim, pv), qc = jax.device_get(
-                ((pairs.idx1, pairs.idx2, pairs.sim, pairs.valid), qc))
+        with self.telemetry.tracer.span("fused_step", station="pool") as step:
+            with self.telemetry.tracer.span("put", station="pool") as sp:
+                dev = [put(x) for x in host]
+                bid = jnp.int32(base_id)
+                sp.set(bytes=sum(x.nbytes for x in host))
+            with self.telemetry.tracer.span("dispatch", station="pool"):
+                if advance:
+                    self.pstate, pairs, qc = \
+                        fused_mod.pool_step_advance_sharded(
+                            self.pstate, dev[0], self._pool_mappings, bid,
+                            fcfg, lcfg, window, sat, dup, occ, ctr, mp, ver,
+                            mj, mesh=self.mesh)
+                else:
+                    self.pstate, pairs, qc = \
+                        fused_mod.pool_step_block_sharded(
+                            self.pstate, dev[0], self._pool_mappings, bid,
+                            dev[1], fcfg, lcfg, window, sat, dup, occ, ctr,
+                            mp, ver, mj, mesh=self.mesh)
+                    self._halo_ok = clean or primed
+            # one transfer for the whole pooled step output, after the
+            # wait for the device
+            out = ((pairs.idx1, pairs.idx2, pairs.sim, pairs.valid), qc)
+            with self.telemetry.tracer.span("wait", station="pool"):
+                jax.block_until_ready(out)
+            with self.telemetry.tracer.span("pull", station="pool") as sp:
+                (i1, i2, sim, pv), qc = jax.device_get(out)
+                sp.set(bytes=i1.nbytes + i2.nbytes + sim.nbytes + pv.nbytes
+                       + qc.nbytes)
         # one watchdog step per pooled dispatch (all stations share it)
-        self.telemetry.record_fused_wall("pool", wd.step_end())
-        t_host = time.perf_counter()
+        self.telemetry.record_fused_wall("pool", step.dur_s)
         emitted = 0
-        with self.telemetry.tracer.span("host_tail", station="pool"):
+        with self.telemetry.tracer.span("host_tail", station="pool") as tail:
             for i, st in enumerate(self.stations):
                 st._absorb_qc(qc[i], n_adv - int(vm[i, :n_adv].sum()))
                 emitted += st._consume(base_id, n_adv, int(vm[i].sum()),
                                        (i1[i], i2[i], sim[i], pv[i]))
-        self.telemetry.record_host_tail("pool",
-                                        time.perf_counter() - t_host)
+            tail.set(pairs=emitted)
+        self.telemetry.record_host_tail("pool", tail.dur_s)
         return emitted
 
     def _pool_flush(self) -> int:
         """Pool counterpart of ``StationStream.flush`` (lockstep rings ⇒
         every station tails at the same base id / consumed count)."""
         emitted = 0
-        ready = 0
-        per_st = [st.ring.flush_ready() for st in self.stations]
-        for k in range(len(per_st[0])):
-            base_id = per_st[0][k][0]
-            blocks = np.stack([per_st[i][k][1]
-                               for i in range(len(self.stations))])
-            masks = [per_st[i][k][2] for i in range(len(self.stations))]
-            ready += self._pool_ingest_block(base_id, blocks, masks)
-        parts = [st.ring.flush_partial() for st in self.stations]
-        part = parts[0]
-        if part is not None:
-            parts = [(p[0], p[1],
-                      st._flag_duplicates(p[0], p[1], p[2],
-                                          end_id=st.ring.next_fp))
-                     for st, p in zip(self.stations, parts)]
-            part = parts[0]
-        blocks = (np.stack([p[1] for p in parts])
-                  if part is not None else None)
+        with self.telemetry.tracer.span("ingest", station="pool"):
+            ready = self._pool_dedup(self._pool_blocks(
+                [st.ring.flush_ready() for st in self.stations]))
+            parts = [st.ring.flush_partial() for st in self.stations]
+            part = None
+            if parts[0] is not None:
+                (part,) = self._pool_dedup(
+                    self._pool_blocks([[p] for p in parts]), tail=True)
+        ready = sum(self._pool_ingest_block(*b) for b in ready)
         if self.pstate is None:
             if part is not None:
                 coeffs = np.asarray(pool_block_coeffs(
-                    jnp.asarray(blocks), self.cfg.fingerprint))
+                    jnp.asarray(part[1]), self.cfg.fingerprint))
                 for i, st in enumerate(self.stations):
-                    st.mad.update(coeffs[i][parts[i][2]])
+                    st.mad.update(coeffs[i][part[2][i]])
             if any(st.mad.filled < 2 for st in self.stations):
                 return ready
             self._freeze_pool()
             emitted += self._drain_pool()
         emitted += ready
         if part is not None:
-            base_id = part[0]
-            masks = [p[2] for p in parts]
+            base_id, blocks, masks = part
             n_adv = self.stations[0].ring.next_fp - base_id
             emitted += self._pool_process(base_id, blocks, masks=masks,
                                           primed=False, n_adv=n_adv)
@@ -1905,7 +1951,8 @@ def ingest_chunks(det: StreamingDetector, waveforms: np.ndarray,
     first chunks (trace compilation + stats freeze) from the timed span.
     ``metrics_every`` > 0 turns on the live health surface: every N
     pushed chunks a heartbeat line (real-time factor, throughput, drop
-    rates, quality counters) goes to ``heartbeat`` and, when
+    rates, quality counters) goes to ``heartbeat``, the span tracer's
+    buffered JSONL records are flushed, and, when
     ``metrics_file`` is set, the Prometheus text exposition is rewritten
     atomically at the same cadence (a scrape never sees a torn file).
     ``on_chunk(ci)`` runs after each pushed chunk — the interleave hook
@@ -1938,6 +1985,8 @@ def ingest_chunks(det: StreamingDetector, waveforms: np.ndarray,
             det.snapshot(snapshot_dir, step=ci + 1)
         if metrics_every and pushed % metrics_every == 0:
             heartbeat(det.telemetry.heartbeat_line(det))
+            # the buffered span records reach the log at each heartbeat
+            det.telemetry.tracer.flush()
             if metrics_file:
                 det.telemetry.write_prometheus(metrics_file, det)
         if on_chunk is not None:

@@ -118,13 +118,20 @@ def _chunk_core(index: IndexState, med: jax.Array, mad: jax.Array,
     the bit-packed fingerprints the binarizer already produces feed the
     ``IndexState.pk`` ring, so fingerprint → hash → bucket → query →
     verify → compact is literally one fused device program.
+
+    Each stage runs under a ``jax.named_scope`` (``fingerprint``,
+    ``hash`` here; ``expire``, ``dup_guard``, ``insert``, ``query``,
+    ``limit``, ``compact``, ``verify`` in ``guarded_step``), so every
+    device operation of the step carries its stage in its op metadata.
     """
-    coeffs = fp_mod.coeffs_from_waveform(wave, fcfg)
-    bits, packed = fp_mod.binarize_coeffs(coeffs, fcfg, (med, mad))
+    with jax.named_scope("fingerprint"):
+        coeffs = fp_mod.coeffs_from_waveform(wave, fcfg)
+        bits, packed = fp_mod.binarize_coeffs(coeffs, fcfg, (med, mad))
     n = bits.shape[0]
-    sigs, buckets = lsh_mod.signatures_and_buckets(
-        bits, mappings, lcfg, index.shape[1], valid=valid)
-    ids = base_id + jnp.arange(n, dtype=jnp.int32)
+    with jax.named_scope("hash"):
+        sigs, buckets = lsh_mod.signatures_and_buckets(
+            bits, mappings, lcfg, index.shape[1], valid=valid)
+        ids = base_id + jnp.arange(n, dtype=jnp.int32)
     return index_mod.guarded_step(index, sigs, buckets, ids, valid, lcfg,
                                   window, saturation=saturation,
                                   dup_tables=dup_tables,

@@ -499,72 +499,86 @@ def guarded_step(state: IndexState, sigs: jax.Array, buckets: jax.Array,
     threshold in-dispatch, so downstream thresholds can act on exact
     Jaccard instead of the hash-match proxy. All knobs at 0 leave the
     dense emission and the traced program exactly as before.
+
+    The stages run under ``jax.named_scope`` names ``expire``,
+    ``dup_guard``, ``insert``, ``query``, ``limit``, ``compact`` and
+    ``verify``, which every device operation carries in its metadata.
     """
-    if occ_limit > 0:
-        # recycle the incoming ids' partner-count slots (window decay:
-        # a slot's previous owner is a full ring behind — outside any
-        # window the ring was sized for)
-        ring = state.occ.shape[0]
-        state = dataclasses.replace(
-            state, occ=state.occ.at[ids % ring].set(0))
-    if window > 0:
-        # newest = one past the last valid id (prefix masks reduce to
-        # base + n_valid, the pre-quality behavior; hole-y gap masks
-        # still anchor the window to absolute stream time)
-        newest = (ids[-1] + 1 if valid is None
-                  else jnp.max(jnp.where(valid, ids + 1, ids[0])))
-        state = expire(state, newest - jnp.int32(window),
-                       half_life=window if saturation > 0 else 0)
+    with jax.named_scope("expire"):
+        if occ_limit > 0:
+            # recycle the incoming ids' partner-count slots (window decay:
+            # a slot's previous owner is a full ring behind — outside any
+            # window the ring was sized for)
+            ring = state.occ.shape[0]
+            state = dataclasses.replace(
+                state, occ=state.occ.at[ids % ring].set(0))
+        if window > 0:
+            # newest = one past the last valid id (prefix masks reduce to
+            # base + n_valid, the pre-quality behavior; hole-y gap masks
+            # still anchor the window to absolute stream time)
+            newest = (ids[-1] + 1 if valid is None
+                      else jnp.max(jnp.where(valid, ids + 1, ids[0])))
+            state = expire(state, newest - jnp.int32(window),
+                           half_life=window if saturation > 0 else 0)
     ins_valid, qvalid = valid, None
     qc_dup = jnp.int32(0)
     if dup_tables > 0:
-        n = sigs.shape[0]
-        v = jnp.ones((n,), bool) if valid is None else valid
-        dup = duplicate_flags(state, sigs, ids, cfg, dup_tables,
-                              buckets=buckets, valid=v)
-        ins_valid = v & ~dup
-        qvalid = ins_valid
-        qc_dup = dup.sum(dtype=jnp.int32)
-    if verify > 0:
-        # stash this block's bit-packed fingerprints in the ring so the
-        # verify epilogue can gather both endpoints of any within-window
-        # pair (suppressed rows never pair, so their slots stay stale)
-        assert max_pairs > 0, "verify requires max_pairs (compaction)"
-        ring = state.pk.shape[0]
-        wv = (jnp.ones(ids.shape, bool) if ins_valid is None else ins_valid)
-        slot = jnp.where(wv, ids % jnp.int32(ring), jnp.int32(ring))
-        state = dataclasses.replace(
-            state, pk=state.pk.at[slot].set(packed.astype(jnp.uint32),
-                                            mode="drop"))
-    state = insert(state, sigs, ids, cfg, valid=ins_valid, buckets=buckets)
-    qc_sat = (saturated_lookup_count(state, buckets, saturation,
-                                     valid=ins_valid)
-              if saturation > 0 else jnp.int32(0))
-    qc_raw = qc_quar = jnp.int32(0)
-    if counters:
-        pairs, qcounts = query(state, sigs, ids, cfg, buckets=buckets,
-                               qvalid=qvalid, saturation=saturation,
-                               counts=1)
-        qc_raw, qc_quar = qcounts[0], qcounts[1]
-    else:
-        pairs = query(state, sigs, ids, cfg, buckets=buckets, qvalid=qvalid,
-                      saturation=saturation)
+        with jax.named_scope("dup_guard"):
+            n = sigs.shape[0]
+            v = jnp.ones((n,), bool) if valid is None else valid
+            dup = duplicate_flags(state, sigs, ids, cfg, dup_tables,
+                                  buckets=buckets, valid=v)
+            ins_valid = v & ~dup
+            qvalid = ins_valid
+            qc_dup = dup.sum(dtype=jnp.int32)
+    with jax.named_scope("insert"):
+        if verify > 0:
+            # stash this block's bit-packed fingerprints in the ring so
+            # the verify epilogue can gather both endpoints of any
+            # within-window pair (suppressed rows never pair, so their
+            # slots stay stale)
+            assert max_pairs > 0, "verify requires max_pairs (compaction)"
+            ring = state.pk.shape[0]
+            wv = (jnp.ones(ids.shape, bool) if ins_valid is None
+                  else ins_valid)
+            slot = jnp.where(wv, ids % jnp.int32(ring), jnp.int32(ring))
+            state = dataclasses.replace(
+                state, pk=state.pk.at[slot].set(packed.astype(jnp.uint32),
+                                                mode="drop"))
+        state = insert(state, sigs, ids, cfg, valid=ins_valid,
+                       buckets=buckets)
+    with jax.named_scope("query"):
+        qc_sat = (saturated_lookup_count(state, buckets, saturation,
+                                         valid=ins_valid)
+                  if saturation > 0 else jnp.int32(0))
+        qc_raw = qc_quar = jnp.int32(0)
+        if counters:
+            pairs, qcounts = query(state, sigs, ids, cfg, buckets=buckets,
+                                   qvalid=qvalid, saturation=saturation,
+                                   counts=1)
+            qc_raw, qc_quar = qcounts[0], qcounts[1]
+        else:
+            pairs = query(state, sigs, ids, cfg, buckets=buckets,
+                          qvalid=qvalid, saturation=saturation)
     qc_occ = jnp.int32(0)
     if occ_limit > 0:
-        state, pairs, qc_occ = occurrence_limit_pairs(
-            state, sigs, buckets, ids, qvalid, cfg, pairs, occ_limit)
+        with jax.named_scope("limit"):
+            state, pairs, qc_occ = occurrence_limit_pairs(
+                state, sigs, buckets, ids, qvalid, cfg, pairs, occ_limit)
     qc_overflow = jnp.int32(0)
     if max_pairs > 0:
-        pairs, qc_overflow = compact_pairs(pairs, max_pairs)
-        jac = jnp.zeros(pairs.valid.shape, jnp.float32)
+        with jax.named_scope("compact"):
+            pairs, qc_overflow = compact_pairs(pairs, max_pairs)
+            jac = jnp.zeros(pairs.valid.shape, jnp.float32)
         if verify > 0:
-            jac = verify_pairs(state, pairs, use_pallas=(verify == 2))
-            if min_jac > 0.0:
-                keep = pairs.valid & (jac >= jnp.float32(min_jac))
-                pairs = Pairs(idx1=pairs.idx1, idx2=pairs.idx2,
-                              sim=jnp.where(keep, pairs.sim, 0),
-                              valid=keep)
-                jac = jnp.where(keep, jac, jnp.float32(0.0))
+            with jax.named_scope("verify"):
+                jac = verify_pairs(state, pairs, use_pallas=(verify == 2))
+                if min_jac > 0.0:
+                    keep = pairs.valid & (jac >= jnp.float32(min_jac))
+                    pairs = Pairs(idx1=pairs.idx1, idx2=pairs.idx2,
+                                  sim=jnp.where(keep, pairs.sim, 0),
+                                  valid=keep)
+                    jac = jnp.where(keep, jac, jnp.float32(0.0))
         pairs = VerifiedPairs(idx1=pairs.idx1, idx2=pairs.idx2,
                               sim=pairs.sim, jac=jac, valid=pairs.valid)
     qc_pairs = qc_masked = jnp.int32(0)

@@ -10,17 +10,23 @@ detection hot path:
   registry counters (``step_<field>_total``). These are the device's own
   view of its guard activity, reconciled against the host-side quality
   dicts by the telemetry tests.
-* **wall-time histograms** — chunk ingest wall, fused-dispatch wall, and
-  host-tail wall land in log-bucketed histograms with per-station labels
-  (the pooled dispatch is shared by all stations and is labeled
-  ``station="pool"``).
+* **wall-time histograms** — the push wall (``chunk`` span), the device
+  step seen from the host (``fused_step`` span) and the host tail
+  (``host_tail`` span) land in log-bucketed histograms with per-station
+  labels (the pooled dispatch is shared by all stations and is labeled
+  ``station="pool"``); the engine hands in the span durations, so no
+  interval is timed twice.
 * **StepWatchdog** — the training loop's straggler/hang watchdog
-  (``train/watchdog.py``) wraps the streaming step; flagged steps
-  increment ``straggler_steps_total`` and stay queryable via
-  ``watchdog.events``.
-* **span tracing** — a :class:`~repro.obsv.spans.SpanTracer` (JSONL +
-  optional ``jax.profiler`` hook) is carried here so serving can turn it
-  on with a flag; per-name totals feed ``metrics_snapshot``.
+  (``train/watchdog.py``) observes each ``fused_step`` duration;
+  flagged steps increment ``straggler_steps_total`` and stay queryable
+  via ``watchdog.events``.
+* **span tracing** — a :class:`~repro.obsv.spans.SpanTracer` carries the
+  span tree of every push (``chunk`` → ``ingest``/``dedup``,
+  ``fused_step``/``put``/``dispatch``/``wait``/``pull``, ``host_tail``,
+  ``detections``); each span is also a profiler annotation, and records
+  (ids, parents, realtime ns) buffer in memory for an optional JSONL
+  sink that serving turns on with a flag and flushes at each heartbeat;
+  per-name totals feed ``metrics_snapshot``.
 * **health surface** — ``heartbeat(det)`` builds the periodic liveness
   dict (real-time factor, throughput, drop-rate breakdown, quality
   counters) and ``prometheus(det)`` the text exposition, both consumed by
@@ -116,6 +122,9 @@ class StreamTelemetry:
         return self.raw_walls
 
     def record_fused_wall(self, label: str, wall_s: float) -> None:
+        """One device step seen from the host (the ``fused_step`` span):
+        the histogram, and one step of the straggler watchdog."""
+        self.watchdog.observe(wall_s)
         if self.raw_walls is not None:
             self.raw_walls["fused_step"].append(wall_s)
         self.registry.histogram("fused_step_wall_seconds",

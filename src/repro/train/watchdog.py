@@ -39,6 +39,12 @@ class StepWatchdog:
         assert self._t0 is not None, "step_start not called"
         dt = self.clock() - self._t0
         self._t0 = None
+        return self.observe(dt)
+
+    def observe(self, dt: float) -> float:
+        """One step of ``dt`` seconds timed elsewhere (the streaming
+        detector hands in its ``fused_step`` span's duration); flags a
+        straggler or hang exactly as ``step_end`` does."""
         self.n += 1
         flagged = False
         if dt > self.cfg.hang_timeout_s:

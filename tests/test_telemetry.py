@@ -217,9 +217,222 @@ def test_span_nesting_totals_and_jsonl(tmp_path):
     assert all(r["dur_s"] >= 0 and "ts" in r for r in recs)
 
 
+def test_span_records_ids_parents_and_buffered_sink(tmp_path):
+    """Records carry id / parent / trace and realtime start/end; nothing
+    is written before flush(), and a full buffer is written as the next
+    root span opens."""
+    clk = _FakeClock()
+    path = tmp_path / "spans.jsonl"
+    tr = SpanTracer(jsonl_path=str(path), clock=clk)
+    tr.buffer_spans = 3
+    with tr.span("chunk") as root:
+        clk.t += 1.0
+        with tr.span("ingest") as ing:
+            with tr.span("dedup", station="pool") as dd:
+                clk.t += 0.5
+                dd.set(flagged=2)
+    assert (root.dur_s, ing.dur_s, dd.dur_s) == (1.5, 0.5, 0.5)
+    assert not path.exists()                 # buffered, not written
+    with tr.span("chunk"):                   # the 4th opens: 3 written
+        clk.t += 0.25
+    recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert [r["name"] for r in recs] == ["dedup", "ingest", "chunk"]
+    dedup, ingest, chunk = recs
+    assert chunk["parent"] is None and chunk["trace"] == chunk["id"]
+    assert ingest["parent"] == chunk["id"] == ingest["trace"]
+    assert dedup["parent"] == ingest["id"] and dedup["trace"] == chunk["id"]
+    assert dedup["flagged"] == 2 and dedup["station"] == "pool"
+    assert dedup["path"] == "chunk/ingest/dedup" and dedup["depth"] == 2
+    assert [r["dur_s"] for r in recs] == [0.5, 0.5, 1.5]
+    # realtime stamps: nested intervals, ``ts`` their end in seconds
+    assert (chunk["start_ns"] <= ingest["start_ns"] <= dedup["start_ns"]
+            <= dedup["end_ns"] <= ingest["end_ns"] <= chunk["end_ns"])
+    assert all(r["ts"] == pytest.approx(r["end_ns"] * 1e-9) for r in recs)
+    tr.flush()
+    recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert len(recs) == 4
+    assert recs[3]["parent"] is None and recs[3]["trace"] == recs[3]["id"]
+    assert len({r["id"] for r in recs}) == 4
+    assert tr.totals["chunk"] == [2, pytest.approx(1.75)]
+
+
+def test_spans_on_the_profiler_clock(tmp_path):
+    """Every span is a host event of its name in a profiler trace, and
+    its record's [start_ns, end_ns] lies inside that event once the
+    profile's ``profile_start_time`` is added."""
+    import glob
+    import time
+
+    import jax
+    from jax.profiler import ProfileData
+    path = tmp_path / "spans.jsonl"
+    tr = SpanTracer(jsonl_path=str(path))
+    jax.profiler.start_trace(str(tmp_path / "prof"))
+    try:
+        for _ in range(2):
+            with tr.span("chunk"):
+                with tr.span("ingest"):
+                    with tr.span("dedup"):
+                        time.sleep(0.002)
+                with tr.span("fused_step"):
+                    with tr.span("pull"):
+                        time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    tr.close()
+    recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+    assert len(recs) == 10
+    (xplane,) = glob.glob(str(tmp_path / "prof" / "plugins" / "profile"
+                              / "*" / "*.xplane.pb"))
+    pd = ProfileData.from_file(xplane)
+    start = None
+    events: dict = {}
+    for plane in pd.planes:
+        if plane.name == "Task Environment":
+            start = int(dict(plane.stats)["profile_start_time"])
+    assert start is not None
+    for plane in pd.planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                a = start + int(ev.start_ns)
+                events.setdefault(ev.name, []).append(
+                    (a, a + int(ev.duration_ns)))
+    for r in recs:
+        assert any(a <= r["start_ns"] and r["end_ns"] <= b
+                   for a, b in events.get(r["name"], ())), r
+
+
+# span tree of a push: each name and the name of its parent
+_TREE = {"ingest": "chunk", "dedup": "ingest", "fused_step": "chunk",
+         "put": "fused_step", "dispatch": "fused_step",
+         "wait": "fused_step", "pull": "fused_step",
+         "host_tail": "chunk", "detections": "chunk"}
+
+
+def _check_span_tree(path, expect: set) -> None:
+    recs = [json.loads(ln) for ln in path.read_text().splitlines()]
+    by_id = {r["id"]: r for r in recs}
+    kids: dict = {}
+    for r in recs:
+        kids.setdefault(r["parent"], []).append(r)
+    assert {r["name"] for r in recs} == expect | {"chunk"}
+    for r in recs:
+        if r["name"] == "chunk":
+            assert r["parent"] is None and r["trace"] == r["id"]
+            continue
+        up = by_id[r["parent"]]
+        assert up["name"] == _TREE[r["name"]], (r, up)
+        assert r["trace"] == by_id[r["trace"]]["id"]
+        assert by_id[r["trace"]]["name"] == "chunk"
+        assert up["start_ns"] <= r["start_ns"] <= r["end_ns"] <= up["end_ns"]
+    for r in recs:
+        if r["name"] in ("chunk", "fused_step"):
+            inner = sum(k["dur_s"] for k in kids.get(r["id"], ()))
+            assert r["dur_s"] >= inner, r
+        if r["name"] == "fused_step":
+            assert sorted(k["name"] for k in kids[r["id"]]) == \
+                ["dispatch", "pull", "put", "wait"]
+        if r["name"] in ("put", "pull"):
+            assert r["bytes"] > 0
+    assert all("flagged" in r for r in recs if r["name"] == "dedup")
+    assert all("pairs" in r for r in recs if r["name"] == "host_tail")
+
+
+def _tree_config():
+    from repro.configs.fast_seismic import stream_bounded_smoke_config
+    return dataclasses.replace(stream_bounded_smoke_config(),
+                               dup_window_fingerprints=512)
+
+
+def test_push_span_tree_pooled_and_solo(tmp_path):
+    """A pooled StreamingDetector and a solo StationStream each give one
+    span tree per push: ingest (with dedup), the step's put / dispatch /
+    wait / pull, the host tail, and (pooled) the detections poll."""
+    from repro.core.synth import make_dataset
+    from repro.stream.engine import StationStream
+    cfg = smoke_config()
+    scfg = _tree_config()
+    ds = make_dataset(_base_synth(n_stations=2, duration_s=300.0))
+    wf = np.asarray(ds.waveforms, np.float32)
+    med_mad = _frozen(cfg, wf[0])
+
+    det = StreamingDetector(cfg, scfg, n_stations=2, med_mad=med_mad)
+    assert det.pooled and det.rolling
+    det.telemetry.tracer = SpanTracer(jsonl_path=str(tmp_path / "p.jsonl"))
+    for chunk in np.array_split(wf, 6, axis=1):
+        det.push(chunk)
+    det.telemetry.tracer.close()
+    _check_span_tree(tmp_path / "p.jsonl", set(_TREE))
+    # the registry histograms and the watchdog read the span durations
+    tot = det.telemetry.tracer.totals
+    h = det.telemetry.registry.histogram_merged("fused_step_wall_seconds")
+    assert h.count == det.telemetry.watchdog.n == tot["fused_step"][0] > 0
+    assert det.stations[0].stats.chunks == tot["chunk"][0] == 6
+    assert det.stations[0].stats.wall_total_s == \
+        pytest.approx(tot["chunk"][1])
+
+    st = StationStream(cfg, scfg, med_mad=med_mad)
+    st.telemetry.tracer = SpanTracer(jsonl_path=str(tmp_path / "s.jsonl"))
+    for chunk in np.array_split(wf[0], 6):
+        st.push(chunk)
+    st.telemetry.tracer.close()
+    _check_span_tree(tmp_path / "s.jsonl", set(_TREE) - {"detections"})
+
+
+def test_pool_step_lowering_carries_stage_scopes():
+    """Every stage of the pool step runs under its named scope, which the
+    lowered program's locations carry."""
+    from repro.configs.fast_seismic import stream_compact_smoke_config
+    from repro.stream import fused
+    from repro.stream.index import StreamIndexConfig
+    cfg = smoke_config()
+    scfg = dataclasses.replace(
+        stream_compact_smoke_config(), window_fingerprints=2048,
+        saturation_limit=10, dup_sig_tables=2, occ_limit=30,
+        index=StreamIndexConfig(n_buckets=2048, bucket_cap=8,
+                                pk_slots=4096, occ_slots=4096))
+    wave = np.zeros((2, 4096), np.float32)
+    det = StreamingDetector(cfg, scfg, n_stations=2,
+                            med_mad=_frozen(cfg, wave[0]))
+    adv = np.zeros((2, det.stations[0].ring.advance), np.float32)
+    lowered = fused.pool_step_advance.lower(
+        det.pstate, adv, det._pool_mappings, np.int32(0), cfg.fingerprint,
+        cfg.lsh, scfg.window_fingerprints, scfg.saturation_limit,
+        scfg.dup_sig_tables, scfg.occ_limit, 1, scfg.max_pairs_per_block,
+        scfg.verify_code, scfg.verify_min_jaccard)
+    text = lowered.as_text(debug_info=True)
+    for scope in ("fingerprint", "hash", "expire", "dup_guard", "insert",
+                  "query", "limit", "compact", "verify"):
+        # a scope under vmap reads ``vmap(<scope>)``
+        assert re.search(rf'"jit\(pool_step_advance\)[^"]*[/(]{scope}[)/]',
+                         text), scope
+
+
 # ---------------------------------------------------------------------------
 # watchdog integration
 # ---------------------------------------------------------------------------
+
+
+def test_watchdog_observe_flags_the_same_straggler():
+    """``observe(dt)`` (the detector hands in its span's duration) flags
+    exactly what ``step_start``/``step_end`` flag for the same steps."""
+    steps = [0.1] * 6 + [0.5] + [0.1] * 3 + [400.0]
+    clk = _FakeClock()
+    timed = StepWatchdog(WatchdogConfig(min_samples=2, straggler_factor=2.0,
+                                        hang_timeout_s=300.0), clock=clk)
+    told = StepWatchdog(WatchdogConfig(min_samples=2, straggler_factor=2.0,
+                                       hang_timeout_s=300.0))
+    for dt in steps:
+        timed.step_start()
+        clk.t += dt
+        assert timed.step_end() == pytest.approx(dt)
+        assert told.observe(dt) == dt
+    assert [e["reason"] for e in told.events] == ["straggler", "hang"]
+    assert [(e["step"], e["reason"]) for e in told.events] == \
+        [(e["step"], e["reason"]) for e in timed.events]
+    assert told.ema == pytest.approx(timed.ema) and told.n == timed.n
 
 
 def test_watchdog_straggler_counts_and_callback_chain():
