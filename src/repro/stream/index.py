@@ -131,7 +131,12 @@ _bucket_ids = lsh_mod.bucket_ids
 
 def _insert_one_table(sig_tb, ids_tb, cursor_tb, traffic_tb, buckets, keys,
                       new_ids, valid):
-    """Scatter one batch into one table's (B, C) bucket arrays."""
+    """Scatter one batch into one table's (B, C) bucket arrays.
+
+    The scatter indexes (bucket row, ring position) on the (B, C) arrays
+    as they are: a flat ``B * C`` view would need C minor, which the TPU
+    pads from 8 to 128 lanes, relaying out the whole table twice a step.
+    """
     b, c = sig_tb.shape
     n = buckets.shape[0]
     order_key = jnp.where(valid, buckets, jnp.int32(b))  # invalid rows last
@@ -141,12 +146,11 @@ def _insert_one_table(sig_tb, ids_tb, cursor_tb, traffic_tb, buckets, keys,
     _, lens = run_lengths(sb)
     keep = (sb < b) & (rank >= lens - c)   # newest C of each bucket run
     pos = (cursor_tb[jnp.where(sb < b, sb, 0)] + rank) % c
-    slot = jnp.where(keep, sb * c + pos, b * c)  # OOB → dropped
+    row = jnp.where(keep, sb, b)           # OOB row → dropped
     k_s = keys[perm]
     id_s = new_ids[perm]
-    new_sig = sig_tb.reshape(-1).at[slot].set(k_s, mode="drop").reshape(b, c)
-    new_ids_tb = ids_tb.reshape(-1).at[slot].set(id_s, mode="drop") \
-        .reshape(b, c)
+    new_sig = sig_tb.at[row, pos].set(k_s, mode="drop")
+    new_ids_tb = ids_tb.at[row, pos].set(id_s, mode="drop")
     # advance cursors by the full run length (ring continues past drops);
     # the traffic counter advances identically but may later decay
     adds = valid.astype(jnp.int32)

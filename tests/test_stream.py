@@ -132,6 +132,50 @@ def test_index_valid_mask_not_stored(rng):
     assert SI.index_stats(state)["resident"] == 4 * CFG.n_tables
 
 
+def _ring_insert_model(m, sigs, ids, valid, buckets):
+    """Plain ring buffers: each valid row, in batch order, takes its
+    bucket's next position mod C in every table; later rows overwrite."""
+    c = m["sig"].shape[2]
+    for i in np.flatnonzero(valid):
+        for tb, bk in enumerate(buckets[i]):
+            p = m["cursor"][tb, bk] % c
+            m["sig"][tb, bk, p] = sigs[i, tb]
+            m["ids"][tb, bk, p] = ids[i]
+            m["cursor"][tb, bk] += 1
+            m["traffic"][tb, bk] += 1
+    m["inserted"] += int(valid.sum())
+
+
+@pytest.mark.parametrize("t,b,c,n,invalid,cursor0", [
+    (1, 4, 4, 32, 0.0, 0),       # bucket runs longer than C evict in-batch
+    (3, 16, 4, 24, 0.35, 0),     # invalid rows never land
+    (2, 8, 4, 16, 0.0, 997),     # cursors far past C wrap the ring
+    (20, 64, 8, 64, 0.2, 5),     # many tables at once
+], ids=["run_over_cap", "invalid_rows", "cursor_wraps", "many_tables"])
+def test_index_insert_matches_ring_model(rng, t, b, c, n, invalid, cursor0):
+    """The (bucket, position) scatter equals per-bucket ring buffers, bit
+    for bit, over several batches."""
+    cfg = L.LSHConfig(n_tables=t, n_funcs=4, n_matches=1, bucket_cap=8,
+                      min_dt=1, occurrence_frac=0.0)
+    state = SI.init_index(cfg, StreamIndexConfig(n_buckets=b, bucket_cap=c))
+    cursor = rng.integers(cursor0, cursor0 + c + 1, (t, b)).astype(np.int32)
+    state = dataclasses.replace(state, cursor=jnp.asarray(cursor))
+    m = {k: np.array(getattr(state, k))
+         for k in ("sig", "ids", "cursor", "traffic", "inserted")}
+    for k in range(4):
+        sigs = rng.integers(0, 2**32, (n, t), dtype=np.uint32)
+        ids = np.arange(k * n, (k + 1) * n, dtype=np.int32)
+        valid = rng.random(n) >= invalid
+        buckets = rng.integers(0, b, (n, t)).astype(np.int32)
+        state = SI.insert(state, jnp.asarray(sigs), jnp.asarray(ids), cfg,
+                          valid=jnp.asarray(valid),
+                          buckets=jnp.asarray(buckets))
+        _ring_insert_model(m, sigs, ids, valid, buckets)
+    for k, want in m.items():
+        np.testing.assert_array_equal(np.asarray(getattr(state, k)), want,
+                                      err_msg=k)
+
+
 # ---------------------------------------------------------------------------
 # in-dispatch §6.5 occurrence limiter + window-relative saturation (ISSUE 5)
 # ---------------------------------------------------------------------------
