@@ -5,7 +5,9 @@
 
 * the cell's configuration, ``bench/configs/<file>``: every field of the
   program's configuration dataclasses, plus the deployment it stands for
-  (its station count);
+  (its ``stations``, the ``channels`` each records, default 1, and the
+  ``layout`` module its streams follow, default ``bench/layouts/network.py``;
+  a ``layout`` path that does not exist fails the set-up);
 * its traffic mix, ``bench/traffic/<traffic>.json`` (``traffic.py``): the
   events, which the generator lays over the configuration's stations;
 * each metric's reader, ``bench/metrics/<name>.py`` (or the reader of
@@ -20,7 +22,30 @@ runs. Set-up builds the detector with frozen statistics, then pushes the
 first chunks until three blocks have gone through both step entries, so
 every program the window runs is compiled (or loaded from the persistent
 cache) before it starts, and makes the window's chunks. After the window
-every station's output is compared with the plain reference.
+every stream's output is compared with the plain reference.
+
+The stream layout. The program's pool holds one member per stream: the
+configuration's stations, each with its components, station-major and
+component-minor (stream ``i`` is component ``i % channels`` of station
+``i // channels`` in the default layout). A layout module provides
+
+* ``streams(conf)``: the station of each stream;
+* ``make_stream(conf, mix, seed, lag, chunk_samples)``: the seeded
+  stream, whose ``chunk(k)`` and ``span(n)`` are ``(streams, samples)``;
+* ``frozen_stats(conf, mix, stream)``: (median, MAD) per stream;
+* ``make_detector(cfg, scfg, med, mad)``: the detector, through
+  ``program``;
+* ``install_taps(det, annotate)``: the taps the check reads;
+* ``processed(det)``: the fingerprints each stream has put through the
+  step;
+* ``compare(conf, stream, stats, n_fp, pk_rows, taps, overflow,
+  limits)``: the checks, each ``{"value", "limit"}``.
+
+Everything else is here. Of what the metric readers get (``ctx``),
+``stations`` and ``station_s`` count the configuration's stations, so a
+station of three components is one station-hour an hour; ``blocks``
+counts one stream's blocks (every stream advances together); ``work`` is
+one step's work of the streams on the busiest chip.
 """
 from __future__ import annotations
 
@@ -39,6 +64,7 @@ import numpy as np
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
 WARMUP_BLOCKS = 3
+DEFAULT_LAYOUT = "bench/layouts/network.py"
 
 
 class NoAccelerator(RuntimeError):
@@ -76,17 +102,31 @@ def metrics_of(spec: dict, workload: str, traced: bool) -> list[dict]:
             if "workloads" not in m or workload in m["workloads"]]
 
 
+def _load(path: pathlib.Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
 def reader(name: str, directory: pathlib.Path = BENCH / "metrics"):
     """The ``read(ctx)`` function of metric ``name``."""
     for stem in (name, name.split(".")[0]):
         path = directory / f"{stem}.py"
         if path.exists():
-            spec = importlib.util.spec_from_file_location(
-                f"bench_metric_{stem.replace('.', '_')}", path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod.read
+            return _load(path, f"bench_metric_{stem.replace('.', '_')}").read
     raise FileNotFoundError(f"no reader for metric {name!r} in {directory}")
+
+
+def layout_of(conf: dict, root: pathlib.Path = ROOT):
+    """The layout module the configuration names, relative to the root of
+    the checkout; never a fallback for a path that is not there."""
+    rel = conf.get("layout", DEFAULT_LAYOUT)
+    path = root / rel
+    if not path.is_file():
+        raise FileNotFoundError(f"configuration {conf.get('name')!r} names "
+                                f"the layout {rel!r}, which does not exist")
+    return _load(path, f"bench_layout_{path.stem}")
 
 
 def limits_for(workload: str, directory: pathlib.Path = BENCH / "limits"
@@ -189,8 +229,9 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
     step entries before the taps do, to break the timed path underneath
     what the check reads."""
     import jax
-    from bench import program, reference, traffic, work
+    from bench import program, reference, work
 
+    layout = layout_of(conf)
     chips = int(cell["chips"])
     info = device_info(jax, chips, require_tpu)
     from repro.compile_cache import enable_compile_cache
@@ -205,28 +246,29 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
     lag = reference.lag_samples(fp)
     fs = fp["fs"]
     n_st = int(conf["stations"])
-    stream = traffic.NetworkStream(mix, n_st, seed, fs, lag, nb * lag)
+    n_streams = layout.streams(conf).size
+    stream = layout.make_stream(conf, mix, seed, lag, nb * lag)
 
     t0 = time.perf_counter()
-    first = stream.span(reference.span_samples(fp, mix.stats_fingerprints))
-    stats = [reference.frozen_stats(fp, first[s]) for s in range(n_st)]
-    med = np.stack([s[0] for s in stats])
-    mad = np.stack([s[1] for s in stats])
-    log(f"frozen statistics for {n_st} stations over "
-        f"{mix.stats_fingerprints} fingerprints: "
+    med, mad = layout.frozen_stats(conf, mix, stream)
+    log(f"frozen statistics for {n_streams} streams of {n_st} stations "
+        f"over {mix.stats_fingerprints} fingerprints: "
         f"{time.perf_counter() - t0:.2f}s")
 
     t0 = time.perf_counter()
-    det = program.make_detector(cfg, scfg, med, mad)
+    det = layout.make_detector(cfg, scfg, med, mad)
     state_bytes = sum(x.nbytes for x in jax.tree.leaves(det.pstate.index))
-    log(f"detector: {n_st} stations, pool state {state_bytes} bytes, mesh "
-        f"{None if det.mesh is None else det.mesh.devices.size}: "
+    log(f"detector: {n_streams} streams, pool state {state_bytes} bytes, "
+        f"mesh {None if det.mesh is None else det.mesh.devices.size}: "
         f"{time.perf_counter() - t0:.2f}s")
+    held = len(layout.processed(det))
+    if held != n_streams:
+        raise ValueError(f"the layout's detector holds {held} streams, its "
+                         f"layout names {n_streams}")
     annotate = _annotate(jax, traced)
     if fault is not None:
         fault()
-    taps = program.Taps()
-    taps.install(det, range(n_st), annotate)
+    taps = layout.install_taps(det, annotate)
     poll_orig = det.poll_detections
     poll_s = [0.0, 0]
 
@@ -254,7 +296,7 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
         t0 = time.perf_counter()
         k = 0
         warm_s = []
-        while det.stations[0].processed_fp < WARMUP_BLOCKS * nb:
+        while layout.processed(det)[0] < WARMUP_BLOCKS * nb:
             chunk = stream.chunk(k)
             ts = time.perf_counter()
             det.push(chunk)
@@ -265,13 +307,13 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
         t1 = time.perf_counter()
         ahead = [stream.chunk(k + i)
                  for i in range(int(1.5 * seconds / warm_s[-1]) + 2)]
-        log(f"warm-up: {k} pushes, {det.stations[0].processed_fp} "
-            f"fingerprints per station, pushes {json.dumps(warm_s)}: "
+        log(f"warm-up: {k} pushes, {layout.processed(det)[0]} "
+            f"fingerprints per stream, pushes {json.dumps(warm_s)}: "
             f"{t1 - t0:.2f}s; {len(ahead)} chunks made for the window: "
             f"{time.perf_counter() - t1:.2f}s")
         tracer = det.telemetry.tracer
         spans0 = {name: tuple(v) for name, v in tracer.totals.items()}
-        fp0 = det.stations[0].processed_fp
+        fp0 = layout.processed(det)[0]
         poll0 = tuple(poll_s)
         trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if traced else None
         setup_s = time.perf_counter() - t_start
@@ -311,7 +353,7 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
                  for name, v in tracer.totals.items()}
         spans["poll"] = (poll_s[1] - poll0[1], poll_s[0] - poll0[0])
         spans["push"] = (len(push_s), float(sum(push_s)))
-        blocks = (det.stations[0].processed_fp - fp0) // nb
+        blocks = (layout.processed(det)[0] - fp0) // nb
         overflow = det.telemetry.drop_breakdown()["overflow_pairs"]
         slow = sorted(range(len(push_s)), key=push_s.__getitem__)[-3:]
         log(f"window: {len(push_s)} pushes, {blocks} blocks, "
@@ -322,13 +364,14 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
             f"{json.dumps(det.telemetry.drop_breakdown())}; memory peak "
             f"{peak} bytes")
 
-        # work of one step on the busiest chip
-        per_chip = -(-det.pstate.halo.shape[0] // chips)
-        win_rows = [r for st in range(n_st) for r in taps.pairs[st]
+        # work of one step on the busiest chip: its streams, each a
+        # station's step
+        per_chip = -(-n_streams // chips)
+        win_rows = [r for rows in taps.pairs.values() for r in rows
                     if r[0] >= len(taps.jac) - blocks]
         pairs_per = (sum(r[1].size for r in win_rows)
-                     / max(1, blocks * n_st))
-        log(f"max pairs in one block of a station: "
+                     / max(1, blocks * n_streams))
+        log(f"max pairs in one block of a stream: "
             f"{max((r[1].size for r in win_rows), default=0)} of "
             f"{scfg.max_pairs_per_block}")
         one = work.station_step(fp, lsh, idx, nb, pairs_per)
@@ -358,7 +401,7 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
         if traced:
             pk = work.peaks(info["kind"])
             t_min, bound = work.least_time(step_work, pk)
-            log(f"step work per chip ({per_chip} stations): "
+            log(f"step work per chip ({per_chip} streams): "
                 f"{step_work['flops']:.6g} FLOPs, {step_work['bytes']:.6g} "
                 f"bytes, {step_work['compares']:.6g} Min-Max compares (no "
                 f"published peak, not in the bound); least time "
@@ -370,16 +413,16 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
                 values[m["name"]] = {"value": float(v), "unit": m["unit"]}
 
         # what the timed path produced, read back before the state goes
-        n_fp = [det.stations[st].processed_fp for st in range(n_st)]
-        pk_rows = [np.asarray(det.pstate.index.pk[st, :n_fp[st]])
-                   for st in range(n_st)]
+        n_fp = layout.processed(det)
+        pk_rows = [np.asarray(det.pstate.index.pk[i, :n])
+                   for i, n in enumerate(n_fp)]
         del det, poll, poll_orig
         program.Taps.uninstall()
         gc.collect()
         t0 = time.perf_counter()
-        checks = compare(conf, stream, (med, mad), n_fp, pk_rows, taps,
-                         overflow, limits)
-        log(f"reference check of all {n_st} stations: "
+        checks = layout.compare(conf, stream, (med, mad), n_fp, pk_rows,
+                                taps, overflow, limits)
+        log(f"reference check of all {n_streams} streams: "
             f"{time.perf_counter() - t0:.2f}s")
         correct = all(c["value"] <= c["limit"] for c in checks.values())
         device = dict(info, memory_peak_bytes=peak)
@@ -397,76 +440,6 @@ def run_cell(cell: dict, conf: dict, mix, seed: int, seconds: float,
         watch.close()
         gc.unfreeze()
         program.Taps.uninstall()
-
-
-def compare(conf: dict, stream, stats, n_fp: list, pk_rows: list, taps,
-            overflow: int, limits: dict) -> dict:
-    """The window's output against the plain reference, station by
-    station over the whole pool; each number is the worst station's,
-    beside its limit."""
-    import jax
-    from bench import reference
-    fp, lsh, idx, st = (conf["fingerprint"], conf["lsh"], conf["index"],
-                        conf["stream"])
-    fp_dim = 2 * fp["img_freq"] * fp["img_time"]
-    x_all = stream.span(reference.span_samples(fp, max(n_fp)))
-    jac_host: dict = {}
-    fp_worst = pair_worst = jac_err = 0.0
-    ref_total = 0
-    for s, n in enumerate(n_fp):
-        if n >= st["window_fingerprints"] > 0 or n > idx["pk_slots"]:
-            raise ValueError("the stream outgrew the detection window; the "
-                             "reference assumes nothing expired")
-        x = x_all[s, :reference.span_samples(fp, n)]
-        ref_pk = reference.packed_fingerprints(fp, x, stats[0][s],
-                                               stats[1][s])
-        same_fp = (ref_pk == pk_rows[s]).all(axis=1)
-        fp_worst = max(fp_worst, float(n - same_fp.sum()) / max(1, n))
-        sig, bkt = reference.signatures(lsh, ref_pk, fp_dim,
-                                        idx["n_buckets"])
-        r1, r2, rsim, _ = reference.index_pairs(
-            sig, bkt, st["block_fingerprints"], idx["bucket_cap"],
-            lsh["min_dt"], lsh["n_matches"], st["saturation_limit"],
-            st["occ_limit"], st["max_pairs_per_block"])
-        rows = taps.pairs[s]
-        for k, *_ in rows:
-            if k not in jac_host:
-                jac_host[k] = np.asarray(jax.device_get(taps.jac[k]))
-        g1 = np.concatenate([r[2] for r in rows] + [np.zeros(0, int)])
-        g2 = np.concatenate([r[3] for r in rows] + [np.zeros(0, int)])
-        gsim = np.concatenate([r[4] for r in rows] + [np.zeros(0, int)])
-        gjac = np.concatenate([jac_host[r[0]][s, r[1]] for r in rows]
-                              + [np.zeros(0, np.float32)])
-        # a pair is (idx1, idx2, table count); a pair streamed twice is
-        # a difference too
-        rkey = (r1.astype(np.int64) * n + r2) * 256 + rsim
-        gkey = (g1.astype(np.int64) * n + g2) * 256 + gsim
-        diff = (np.setxor1d(rkey, gkey).size
-                + gkey.size - np.unique(gkey).size)
-        ref_total += rkey.size
-        pair_worst = max(pair_worst, diff / rkey.size if rkey.size
-                         else float(diff > 0))
-        # the verify epilogue alone: pairs both sides emitted, between
-        # fingerprints whose bits agree with the reference
-        _, ri, gi = np.intersect1d(r1.astype(np.int64) * n + r2,
-                                   g1.astype(np.int64) * n + g2,
-                                   return_indices=True)
-        ok = same_fp[r1[ri]] & same_fp[r2[ri]]
-        ri, gi = ri[ok], gi[ok]
-        if ri.size:
-            rj = reference.jaccard(ref_pk[r1[ri]], ref_pk[r2[ri]])
-            jac_err = max(jac_err, float(np.abs(rj - gjac[gi]).max()))
-        log(f"station {s}: {n} fingerprints, {n - int(same_fp.sum())} "
-            f"differ; {rkey.size} reference pairs, {gkey.size} streamed, "
-            f"{diff} different")
-    values = {
-        "fp_mismatch": fp_worst,
-        "pair_mismatch": pair_worst if ref_total else 1.0,
-        "jaccard_err": jac_err,
-        "overflow": float(overflow),
-    }
-    return {name: {"value": v, "limit": float(limits[name])}
-            for name, v in values.items()}
 
 
 def main(argv: list[str], t_start: float) -> int:

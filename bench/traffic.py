@@ -1,12 +1,16 @@
 """Seeded network waveform traffic: noise plus repeating sources.
 
 One general generator reads every traffic mix from its parameter file
-(``bench/traffic/<name>.json``); the station count and the sample rate
-come from the cell's configuration. The stream is one channel per
-station: Gaussian background noise plus repeating events from
-``sources`` templates, one event every ``event_interval_s`` seconds
-across the network, each arriving on every station after a
-per-(source, station) delay. Arrivals are aligned to the
+(``bench/traffic/<name>.json``); the station count, the components a
+station records (``channels``) and the sample rate come from the cell's
+configuration. Each stream is Gaussian background noise plus repeating
+events from ``sources`` templates, one event every ``event_interval_s``
+seconds across the network, each arriving on every station after a
+per-(source, station) delay. A component is one more stream of the
+station: an event appears on all of a station's components at that one
+onset, each component with its own template per source (fixed across the
+repeats) and its own noise; it is a distinct projection of the source,
+not a model of polarisation or particle motion. Arrivals are aligned to the
 fingerprint lag grid so the repeats of one source give near-identical
 fingerprints and hash-collide (sub-lag offsets shift the whole spectral
 image, and such repeats almost never collide at the paper's widths).
@@ -15,7 +19,10 @@ Every seed gets the same number of events at the same rate; the seed
 draws the templates, the order of the sources, the jitter of each event
 inside its interval, the delays, the amplitudes and the noise. Chunk
 ``k`` of the stream is a pure function of (seed, k), so a chunk can be
-made again after the run for the reference.
+made again after the run for the reference. Components after the first
+draw their templates and noise from random streams of their own, so
+component 0 of every station is the one-component stream, byte for
+byte.
 """
 from __future__ import annotations
 
@@ -69,20 +76,27 @@ def _template(rng: np.random.Generator, n: int, fs: float,
 
 class NetworkStream:
     """The stream of one run: ``chunk(k)`` is samples
-    ``[k * chunk_samples, (k + 1) * chunk_samples)`` of every station."""
+    ``[k * chunk_samples, (k + 1) * chunk_samples)`` of every component
+    of every station, station-major and component-minor."""
 
     def __init__(self, mix: TrafficMix, stations: int, seed: int, fs: float,
-                 lag_samples: int, chunk_samples: int):
+                 lag_samples: int, chunk_samples: int, channels: int = 1):
         self.mix = mix
         self.stations = stations
+        self.channels = channels
         self.seed = int(seed) % 2**64
         self.fs = fs
         self.lag = lag_samples
         self.chunk_samples = chunk_samples
         rng = np.random.default_rng([self.seed, 0x7EA])
         n_tpl = int(round(mix.event_duration_s * fs))
-        self.templates = np.stack([_template(rng, n_tpl, fs, mix.event_freq_hz)
-                                   for _ in range(mix.sources)])
+        tpl_rngs = [rng] + [np.random.default_rng([self.seed, 0x7EA, c])
+                            for c in range(1, channels)]
+        # (sources, channels, samples): one template per (source, component)
+        self.templates = np.stack(
+            [np.stack([_template(r, n_tpl, fs, mix.event_freq_hz)
+                       for _ in range(mix.sources)]) for r in tpl_rngs],
+            axis=1)
         self.delays = self.lag * rng.integers(
             mix.delay_lags[0], mix.delay_lags[1],
             (mix.sources, stations))
@@ -100,13 +114,16 @@ class NetworkStream:
             0.9, 1.1, (n_ev, stations))).astype(np.float32)
 
     def chunk(self, k: int) -> np.ndarray:
-        """(stations, chunk_samples) float32."""
+        """(stations * channels, chunk_samples) float32."""
         n = self.chunk_samples
-        rng = np.random.default_rng([self.seed, 1, k])
-        out = rng.standard_normal((self.stations, n), dtype=np.float32)
+        out = np.empty((self.stations, self.channels, n), np.float32)
+        for c in range(self.channels):
+            key = [self.seed, 1, k] + ([c] if c else [])
+            out[:, c] = np.random.default_rng(key).standard_normal(
+                (self.stations, n), dtype=np.float32)
         out *= np.float32(self.mix.noise_sigma)
         s0, s1 = k * n, (k + 1) * n
-        n_tpl = self.templates.shape[1]
+        n_tpl = self.templates.shape[-1]
         reach = int(self.delays.max()) + n_tpl
         lo = np.searchsorted(self.times, s0 - reach, "left")
         hi = np.searchsorted(self.times, s1, "left")
@@ -117,12 +134,12 @@ class NetworkStream:
                 a = self.times[e] + self.delays[src, st]
                 b0, b1 = max(a, s0), min(a + n_tpl, s1)
                 if b0 < b1:
-                    out[st, b0 - s0:b1 - s0] += (self.amps[e, st]
-                                                 * tpl[b0 - a:b1 - a])
-        return out
+                    out[st, :, b0 - s0:b1 - s0] += (self.amps[e, st]
+                                                    * tpl[:, b0 - a:b1 - a])
+        return out.reshape(self.stations * self.channels, n)
 
     def span(self, n_samples: int) -> np.ndarray:
-        """The first ``n_samples`` of the stream, chunk by chunk."""
+        """The first ``n_samples`` of every stream, chunk by chunk."""
         n_chunks = -(-n_samples // self.chunk_samples)
         parts = [self.chunk(k) for k in range(n_chunks)]
         return np.concatenate(parts, axis=1)[:, :n_samples]
