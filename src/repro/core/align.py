@@ -79,9 +79,11 @@ def _sort_triplets(dt, idx1, sim, valid):
 def merge_channels(triplets: Sequence[tuple], threshold: int) -> Pairs:
     """Sum similarity of identical (dt, idx1) across channels; threshold.
 
-    ``triplets``: sequence of (dt, idx1, sim, valid) arrays per channel.
+    ``triplets``: sequence of (dt, idx1, sim, valid) arrays, one per
+    component of a station (a one-component station passes one).
     Implements the paper's sort → merge → reduce with the combined-matrix
-    threshold (§7.1 channel level).
+    threshold (§7.1 channel level). ``merge_channels_host`` is its numpy
+    twin for the rolling filter's window close.
     """
     dt = jnp.concatenate([t[0] for t in triplets])
     idx1 = jnp.concatenate([t[1] for t in triplets])
@@ -97,6 +99,36 @@ def merge_channels(triplets: Sequence[tuple], threshold: int) -> Pairs:
     idx2 = jnp.where(keep, idx_s + dt_s, INVALID)
     return Pairs(idx1=jnp.where(keep, idx_s, INVALID), idx2=idx2,
                  sim=jnp.where(keep, tot[seg], 0), valid=keep)
+
+
+def _runs(*keys: np.ndarray) -> np.ndarray:
+    """Start positions of the runs of equal key tuples (keys sorted)."""
+    new = np.zeros(keys[0].size, bool)
+    new[:1] = True
+    for k in keys:
+        new[1:] |= k[1:] != k[:-1]
+    return np.flatnonzero(new)
+
+
+def merge_channels_host(triplets: Sequence[np.ndarray],
+                        threshold: int) -> np.ndarray:
+    """``merge_channels`` on the host, in numpy, for the rolling filter's
+    window close: per channel an (m, 3) array of (idx1, idx2, sim), out
+    the (k, 3) merged triplets (idx1, idx2, summed sim) that reach
+    ``threshold``, sorted by (dt, idx1) — the jitted merge's valid pairs
+    in its order."""
+    tri = np.concatenate([np.asarray(t, np.int64).reshape(-1, 3)
+                          for t in triplets])
+    if not tri.shape[0]:
+        return tri
+    dt = tri[:, 1] - tri[:, 0]
+    order = np.lexsort((tri[:, 0], dt))
+    dt, idx1, sim = dt[order], tri[order, 0], tri[order, 2]
+    starts = _runs(dt, idx1)
+    tot = np.add.reduceat(sim, starts)
+    keep = tot >= threshold
+    i1 = idx1[starts][keep]
+    return np.stack([i1, i1 + dt[starts][keep], tot[keep]], axis=1)
 
 
 # ---------------------------------------------------------------------------
@@ -170,6 +202,45 @@ def cluster_station(pairs: Pairs, cfg: AlignConfig) -> Events:
                   size=jnp.where(keep, g_count[gid], 0),
                   score=jnp.where(keep, g_score[gid], 0),
                   valid=keep)
+
+
+def cluster_station_host(tri: np.ndarray, cfg: AlignConfig) -> np.ndarray:
+    """``cluster_station`` on the host, in numpy, over (m, 3) triplets
+    (idx1, idx2, sim) with distinct (dt, idx1), as
+    ``merge_channels_host`` gives them: the (k, 5) event rows (dt, onset,
+    extent, size, score) of the jitted clustering's valid events, in its
+    order (the stage-2 sort by onset, then dt)."""
+    tri = np.asarray(tri, np.int64).reshape(-1, 3)
+    if not tri.shape[0]:
+        return np.zeros((0, 5), np.int64)
+    dt = tri[:, 1] - tri[:, 0]
+    order = np.lexsort((tri[:, 0], dt))
+    dt, idx1, sim = dt[order], tri[order, 0], tri[order, 2]
+    # stage 1: a cluster starts on a dt change or an idx1 gap > gap
+    new = np.ones(dt.size, bool)
+    new[1:] = (dt[1:] != dt[:-1]) | (idx1[1:] - idx1[:-1] > cfg.gap)
+    c = np.flatnonzero(new)
+    c_count = np.diff(np.r_[c, dt.size])
+    c_score = np.add.reduceat(sim, c)
+    c_dt, c_imin = dt[c], idx1[c]
+    c_imax = np.maximum.reduceat(idx1, c)
+    # stage 2: one merge pass over clusters sorted by (idx_min, dt), each
+    # against the cluster before it
+    o = np.lexsort((c_dt, c_imin))
+    s_dt, s_imin, s_imax = c_dt[o], c_imin[o], c_imax[o]
+    s_count, s_score = c_count[o], c_score[o]
+    sep = np.ones(o.size, bool)
+    sep[1:] = ((np.abs(s_dt[1:] - s_dt[:-1]) > cfg.dt_merge_tol)
+               | (s_imin[1:] > s_imax[:-1] + cfg.gap))
+    g = np.flatnonzero(sep)
+    g_count = np.add.reduceat(s_count, g)
+    g_score = np.add.reduceat(s_score, g)
+    g_imin = np.minimum.reduceat(s_imin, g)
+    rows = np.stack([np.minimum.reduceat(s_dt, g), g_imin,
+                     np.maximum.reduceat(s_imax, g) - g_imin, g_count,
+                     g_score], axis=1)
+    keep = (g_count >= cfg.min_cluster_size) & (g_score >= cfg.min_cluster_sim)
+    return rows[keep]
 
 
 # ---------------------------------------------------------------------------
@@ -284,6 +355,54 @@ def associate_network(events: Sequence[Events], cfg: AlignConfig,
         out["station_onset"] = jnp.where(keep[:, None], onset_mat[gid],
                                          INVALID)
         out["station_score"] = jnp.where(keep[:, None], score_mat[gid], 0)
+    return out
+
+
+def associate_network_host(rows: Sequence[np.ndarray], cfg: AlignConfig,
+                           with_onsets: bool = False) -> dict:
+    """``associate_network`` on the host, in numpy, over each station's
+    (k, 5) event rows (dt, onset, extent, size, score): the valid groups
+    of the jitted association, in its order, as compact arrays ("dt",
+    "onset", "onset_span", "n_stations", "score", and with
+    ``with_onsets`` the (groups, S) "station_onset" / "station_score").
+    The detector's per-push poll runs this, so an association of any
+    size compiles nothing."""
+    n_stations = len(rows)
+    rows = [np.asarray(r, np.int64).reshape(-1, 5) for r in rows]
+    sid = np.concatenate([np.full(r.shape[0], i, np.int64)
+                          for i, r in enumerate(rows)])
+    allr = np.concatenate(rows)
+    order = np.lexsort((allr[:, 1], allr[:, 0]))
+    dt, on, sc, sid = allr[order, 0], allr[order, 1], allr[order, 4], \
+        sid[order]
+    new = np.ones(dt.size, bool)
+    new[1:] = ((np.abs(dt[1:] - dt[:-1]) > cfg.dt_tol)
+               | (np.abs(on[1:] - on[:-1]) > cfg.onset_tol))
+    g = np.flatnonzero(new)
+    gid = np.cumsum(new) - 1
+    n_g = g.size
+    member = np.zeros((n_g, n_stations), bool)
+    member[gid, sid] = True
+    n_st = member.sum(axis=1)
+    if n_g:
+        g_dt = np.minimum.reduceat(dt, g)
+        g_on = np.minimum.reduceat(on, g)
+        span = np.maximum(np.maximum.reduceat(on, g) - g_on, 0)
+        g_sc = np.add.reduceat(sc, g)
+    else:
+        g_dt = g_on = span = g_sc = np.zeros(0, np.int64)
+    keep = n_st >= cfg.min_stations
+    if cfg.max_group_extent > 0:
+        keep &= span <= cfg.max_group_extent
+    out = {"dt": g_dt[keep], "onset": g_on[keep], "onset_span": span[keep],
+           "n_stations": n_st[keep], "score": g_sc[keep]}
+    if with_onsets:
+        onset_mat = np.full((n_g, n_stations), int(INVALID), np.int64)
+        np.minimum.at(onset_mat, (gid, sid), on)
+        score_mat = np.zeros((n_g, n_stations), np.int64)
+        np.add.at(score_mat, (gid, sid), sc)
+        out["station_onset"] = onset_mat[keep]
+        out["station_score"] = score_mat[keep]
     return out
 
 

@@ -110,20 +110,23 @@ def _locate_tail(detections: dict, waveforms: np.ndarray,
                  stats: dict) -> dict:
     """Batch-replay location/magnitude stage: QC-counter station weights
     → migration stack over the associated groups → relative magnitudes
-    from whole-trace per-fingerprint peak amplitudes. Mutates ``stats``
-    (adds ``moveout_rejected``) and returns a new detections dict with
-    the located columns; ``reject_inconsistent`` masks failing groups
-    out of ``valid``."""
+    from whole-trace per-fingerprint peak amplitudes. ``waveforms`` is
+    (S, C, T) and ``qc_sum`` (S, C, fields): a station's weight counts
+    its components' traffic, and its amplitude is its loudest
+    component's. Mutates ``stats`` (adds ``moveout_rejected``) and
+    returns a new detections dict with the located columns;
+    ``reject_inconsistent`` masks failing groups out of ``valid``."""
     from repro.core import locate as locate_mod
     from repro.stream import index as index_mod
     fcfg = cfg.fingerprint
-    n_stations = waveforms.shape[0]
-    qdicts = [{name: int(qc_sum[st, k])
+    n_stations, channels, n_samples = waveforms.shape
+    qdicts = [{name: int(qc_sum[st, :, k].sum())
                for k, name in enumerate(index_mod.QC_FIELDS)}
               for st in range(n_stations)]
     weights = locate_mod.station_weights(
-        qdicts, [waveforms.shape[1]] * n_stations,
-        [n_fp] * n_stations, cfg.locate)
+        qdicts, [n_samples * channels] * n_stations,
+        [n_fp * channels] * n_stations, cfg.locate)
+    waveforms = np.fmax.reduce(np.abs(waveforms), axis=1)
     fp_amp = [locate_mod.fingerprint_amplitudes(
         waveforms[st], fcfg.lag_samples, fcfg.window_samples)
         for st in range(n_stations)]
@@ -179,8 +182,15 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
                   station_xy: np.ndarray | None = None
                   ) -> tuple[dict, list[Events],
                              StageTimes, dict]:
-    """(n_stations, T) waveforms → network detections, via the streaming
-    core (batch = replay).
+    """(S, C, T) waveforms — S stations of C components each, or (S, T)
+    for one component — → network detections, via the streaming core
+    (batch = replay).
+
+    Every component is a stream of the pool step; per station, each
+    component's pairs pass the §6.5 occurrence filter, then the
+    similarity of each (dt, idx1) is summed over the components and the
+    sum thresholded (``merge_channels``, §7.1 channel level) before
+    ``cluster_station``, and the association counts stations.
 
     Returns (network detections dict, per-station events, stage wall
     times, aggregate stats). ``scfg`` (a ``StreamConfig``) sizes the
@@ -189,8 +199,9 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
     host-loop output bit-exactly. ``n_partitions`` is accepted for API
     compatibility: the replay is partition-bounded by construction (the
     resident index *is* the §6.4 working-set bound), so the knob is a
-    no-op. ``keep_pairs`` stashes the per-station post-filter ``Pairs``
-    under ``stats["_station_pairs"]`` (the golden-pin hook).
+    no-op. ``keep_pairs`` stashes each stream's post-filter ``Pairs``
+    (station-major, component-minor) under ``stats["_station_pairs"]``
+    (the golden-pin hook).
 
     Stage attribution goes through the span layer: each stage runs inside
     a :class:`~repro.obsv.spans.SpanTracer` span and ``StageTimes`` is
@@ -201,21 +212,26 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
     association output additionally carries migration-located origins,
     moveout-consistency flags and relative magnitudes (see
     :mod:`repro.core.locate`); groups failing the moveout check are
-    masked out of ``valid`` when ``cfg.locate.reject_inconsistent``. With ``scfg.telemetry`` on (the default), the replay also
-    collects the in-dispatch ``index.QC_FIELDS`` counters — summed over
-    blocks into ``stats["drops"]`` (per guard, summed over stations) with
-    per-station vectors under ``stats["station<i>_qc"]`` — at no extra
-    dispatch. Span wall totals stay on the tracer (deliberately out of
-    ``stats``, which is compared dict-exact by the golden tests).
+    masked out of ``valid`` when ``cfg.locate.reject_inconsistent``.
+    With ``scfg.telemetry`` on (the default), the replay also collects
+    the in-dispatch ``index.QC_FIELDS`` counters — summed over blocks
+    into ``stats["drops"]`` (per guard, summed over streams) with
+    per-stream vectors under ``stats["station<i>_qc"]`` (stream i) — at
+    no extra dispatch. Span wall totals stay on the tracer (deliberately
+    out of ``stats``, which is compared dict-exact by the golden tests).
     """
     from repro.obsv.spans import SpanTracer
     from repro.stream import fused as fused_mod
     from repro.stream import index as index_mod
-    from repro.stream.engine import host_occurrence_filter, \
-        pairs_from_triplets
+    from repro.stream.engine import align_station, pairs_from_triplets
 
-    waveforms = np.atleast_2d(np.asarray(waveforms, np.float32))
-    n_stations = waveforms.shape[0]
+    waveforms = np.asarray(waveforms, np.float32)
+    if waveforms.ndim < 3:
+        waveforms = np.atleast_2d(waveforms)[:, None, :]
+    n_stations, channels = waveforms.shape[:2]
+    station_waves = waveforms
+    waveforms = waveforms.reshape(n_stations * channels, -1)
+    n_streams = waveforms.shape[0]
     fcfg, lcfg, acfg = cfg.fingerprint, cfg.lsh, cfg.align
     if scfg is None:
         scfg = replay_config(lcfg)
@@ -245,22 +261,22 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
     mj = getattr(scfg, "verify_min_jaccard", 0.0)
     icfg = (scfg.effective_index(fcfg.fp_dim)
             if hasattr(scfg, "effective_index") else scfg.index)
-    qc_sum = np.zeros((n_stations, len(index_mod.QC_FIELDS)), np.int64)
+    qc_sum = np.zeros((n_streams, len(index_mod.QC_FIELDS)), np.int64)
     state = fused_mod.init_pool_state(
-        [index_mod.init_index(lcfg, icfg) for _ in range(n_stations)],
+        [index_mod.init_index(lcfg, icfg) for _ in range(n_streams)],
         fcfg.halo_samples, meds, mads)
     b = scfg.block_fingerprints
     bs = fcfg.block_samples(b)
-    tri: list[list[np.ndarray]] = [[] for _ in range(n_stations)]
+    tri: list[list[np.ndarray]] = [[] for _ in range(n_streams)]
     for base in range(0, n_fp, b):
         with tracer.span("fused_step", base=base):
             n_valid = min(b, n_fp - base)
             start = base * fcfg.lag_samples
-            block = np.zeros((n_stations, bs), np.float32)
+            block = np.zeros((n_streams, bs), np.float32)
             seg = waveforms[:, start:start + bs]
             block[:, :seg.shape[1]] = seg
             vmask = np.broadcast_to(np.arange(b) < n_valid,
-                                    (n_stations, b))
+                                    (n_streams, b))
             state, pairs, qc = fused_mod.pool_step_block(
                 state, jnp.asarray(block), mappings, jnp.int32(base),
                 jnp.asarray(vmask), fcfg, lcfg, scfg.window_fingerprints,
@@ -270,7 +286,7 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
             (i1, i2, sim, pv), qc = jax.device_get(
                 ((pairs.idx1, pairs.idx2, pairs.sim, pairs.valid), qc))
             qc_sum += np.asarray(qc, np.int64)
-            for st in range(n_stations):
+            for st in range(n_streams):
                 m = pv[st]
                 if m.any():
                     tri[st].append(np.stack(
@@ -281,23 +297,20 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
     # shared with the streaming finalize
     with tracer.span("host_tail"):
         station_events: list[Events] = []
-        station_pairs: list[Pairs] = []
+        station_pairs: list[Pairs] = []   # per stream, post-filter
         for st in range(n_stations):
-            tri_st = (np.concatenate(tri[st], axis=0) if tri[st]
-                      else np.zeros((0, 3), np.int64))
-            pairs = pairs_from_triplets(tri_st)
+            events, comps, excluded = align_station(
+                [pairs_from_triplets(np.concatenate(tri[c], axis=0)
+                                     if tri[c] else np.zeros((0, 3)))
+                 for c in range(st * channels, (st + 1) * channels)],
+                n_fp, cfg)
             if lcfg.occurrence_frac > 0 and n_fp > 0:
-                pairs, excluded = host_occurrence_filter(pairs, n_fp, lcfg)
-                stats[f"station{st}_excluded"] = int(excluded.sum())
-            stats[f"station{st}_pairs"] = int(pairs.count())
+                stats[f"station{st}_excluded"] = excluded
+            stats[f"station{st}_pairs"] = sum(int(p.count()) for p in comps)
             stats[f"station{st}_fingerprints"] = n_fp
-            merged = align_mod.merge_channels(
-                [(pairs.dt, pairs.idx1, pairs.sim, pairs.valid)],
-                acfg.channel_threshold)
-            events = align_mod.cluster_station(merged, acfg)
             stats[f"station{st}_events"] = int(events.count())
             station_events.append(events)
-            station_pairs.append(pairs)
+            station_pairs.extend(comps)
 
         with_locate = cfg.locate is not None and station_xy is not None \
             and n_stations >= 2
@@ -305,15 +318,16 @@ def detect_events(waveforms: np.ndarray, cfg: DetectConfig,
             station_events, acfg, n_stations, with_onsets=with_locate)
         jax.block_until_ready(detections["valid"])
         if with_locate:
-            detections = _locate_tail(detections, waveforms, qc_sum, n_fp,
-                                      station_xy, cfg, stats)
+            detections = _locate_tail(
+                detections, station_waves, qc_sum.reshape(
+                    n_stations, channels, -1), n_fp, station_xy, cfg, stats)
     times = StageTimes.from_spans(tracer)
     stats["detections"] = int(np.asarray(detections["valid"]).sum())
     if ctr:
         stats["drops"] = {
             name: int(qc_sum[:, k].sum())
             for k, name in enumerate(index_mod.QC_FIELDS)}
-        for st in range(n_stations):
+        for st in range(n_streams):
             stats[f"station{st}_qc"] = {
                 name: int(qc_sum[st, k])
                 for k, name in enumerate(index_mod.QC_FIELDS)}

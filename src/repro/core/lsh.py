@@ -303,6 +303,22 @@ def occurrence_filter(pairs: Pairs, n_fp: int, frac: float,
     return out, excluded_full
 
 
+def occurrence_keep(idx1: np.ndarray, idx2: np.ndarray, n_fp: int,
+                    frac: float, limit: int | None = None) -> np.ndarray:
+    """``occurrence_filter`` on the host, in numpy: the mask of the pairs
+    (ids in [0, n_fp)) that survive it, bit for bit the jitted filter's
+    ``valid``. The rolling filter's window close runs this, so a close
+    of any size compiles nothing."""
+    cnt = (np.bincount(idx1, minlength=n_fp)
+           + np.bincount(idx2, minlength=n_fp))
+    limit = max(1, int(frac * n_fp)) if limit is None else max(1, int(limit))
+    excluded = cnt > limit
+    full = excluded.copy()
+    full[idx1[excluded[idx2]]] = True
+    full[idx2[excluded[idx1]]] = True
+    return ~full[idx1] & ~full[idx2]
+
+
 # ---------------------------------------------------------------------------
 # whole search (+ partitioned variant, §6.4)
 # ---------------------------------------------------------------------------

@@ -304,7 +304,15 @@ class ServeDetectEngine:
     def refresh_from(self, det: StreamingDetector) -> bool:
         """Version-gated refresh from an ingesting detector: a no-op
         until its statistics freeze, and when no chunk arrived since the
-        pool snapshot this engine already serves."""
+        pool snapshot this engine already serves. A detector whose
+        stations record several components is refused: the engine's
+        answers are per pool member, which would be components, not
+        stations."""
+        if det.channels > 1:
+            raise ValueError(
+                f"serving answers per station, and this detector's "
+                f"stations record {det.channels} components each; serve "
+                f"a one-component detector (channels=1)")
         if not all(st.stats_frozen for st in det.stations):
             return False
         if det.serving_version == self.serving_version:

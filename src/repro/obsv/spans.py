@@ -13,13 +13,19 @@ one tree per push (``StreamingDetector.push`` / ``StationStream.push``)::
     │   ├── wait         block_until_ready on the outputs to pull
     │   └── pull         the one device_get
     ├── host_tail    pair consumption and the rolling filter
+    │   └── filter_close   one station's filter-window close
+    │       ├── occurrence     the §6.5 filter, per component
+    │       ├── merge          the channel sum and threshold
+    │       └── cluster        diagonal clustering
     └── detections   poll_detections (StreamingDetector only)
 
 Spans wrap loops over stations, never one station, so their cost does
-not grow with the network. Entering and leaving a span is one pair of
-reads of ``clock`` (``time.perf_counter`` unless injected), a dict
-update and a ``jax.profiler.TraceAnnotation`` enter/exit, which is a
-cheap call while no profiler runs; while one does, every span is a host
+not grow with the network; a ``filter_close`` tree is one station's,
+once per filter window (hours of stream), not once per push. Entering
+and leaving a span is one pair of reads of ``clock``
+(``time.perf_counter`` unless injected), a dict update and a
+``jax.profiler.TraceAnnotation`` enter/exit, which is a cheap call
+while no profiler runs; while one does, every span is a host
 event of its name in the trace. ``span`` returns the :class:`Span`, whose
 ``dur_s`` the caller reads after exit and whose ``set`` attaches counts
 (bytes put or pulled, pairs consumed, fingerprints flagged).

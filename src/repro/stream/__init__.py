@@ -122,8 +122,9 @@ extra dispatch:
   ride the live noise floor, so the duplicate guard cannot see them and
   the saturation quarantine alone only managed ~2×. The host-side
   ``occurrence_filter`` (one shared invocation,
-  ``engine.host_occurrence_filter``, used by finalize, the rolling
-  filter, and the batch replay driver) remains the bit-exact §6.5
+  ``engine.host_occurrence_filter``, used by finalize and the batch
+  replay driver; the rolling filter runs its numpy twin
+  ``lsh.occurrence_keep``) remains the bit-exact §6.5
   reference/fallback.
 
 With every knob at its default (off) — and on clean data even with the
@@ -358,11 +359,13 @@ scaling curve when ``host_cores`` ≥ the device count.
 
 Unbounded streams run *bounded*: with ``StreamConfig.window_fingerprints``
 the jitted step expires index entries beyond a sliding detection window,
-and with ``filter_window_fingerprints`` the ``RollingPairFilter`` retires
-candidate pairs window-by-window through the §6.5 occurrence filter into
-compact event rows — O(window) host state, near-real-time multi-station
-alerts via ``StreamingDetector.poll_detections``, and exact kill/restore
-via ``snapshot``/``restore`` (checkpointed through ``train/checkpoint``).
+and with ``filter_window_fingerprints`` each station's
+``RollingPairFilter`` retires candidate pairs window-by-window through
+the §6.5 occurrence filter (per component) and the channel merge of the
+station's components into compact event rows — O(window) host state,
+near-real-time multi-station alerts via
+``StreamingDetector.poll_detections``, and exact kill/restore via
+``snapshot``/``restore`` (checkpointed through ``train/checkpoint``).
 
 A parity test (tests/test_stream.py) holds the streamed path to ≥95% of
 the offline ``lsh.search`` pair set on synthetic traces; a golden test
@@ -372,6 +375,7 @@ from repro.stream.engine import (RollingPairFilter,  # noqa: F401
                                  StationStream, StreamingDetector,
                                  StreamStats, block_coeffs, ingest_chunks,
                                  events_from_rows, events_to_rows,
+                                 finalize_station,
                                  host_occurrence_filter,
                                  merge_boundary_rows, pairs_from_triplets,
                                  pool_block_coeffs, stream_step)

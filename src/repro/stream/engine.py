@@ -1,15 +1,18 @@
 """Streaming detector: ring → fingerprints → index → pairs → events.
 
-``StationStream`` owns one station's ingestion state: a ``WaveformRing``
-(chunk framing + halo), a ``StreamingMAD`` (running §5.2 statistics), and
-the device-resident detection state. Each ready block runs one jitted
-fixed-shape step — fingerprint, sign, expire, insert, query — and the
-emitted pairs either accumulate host-side (parity mode) or flow through a
-``RollingPairFilter`` (bounded mode). ``StreamingDetector`` composes
-stations and finishes with the *same* alignment stack as the offline path
-(occurrence filter → channel merge → ``cluster_station`` → network
-association), so a streamed trace yields the same detections as a batch
-re-run, at O(chunk) cost per arrival instead of O(history).
+A station records one or more components (the paper's Z, N, E), and each
+component is one stream. ``StationStream`` owns one stream's ingestion
+state: a ``WaveformRing`` (chunk framing + halo), a ``StreamingMAD``
+(running §5.2 statistics), and the device-resident detection state. Each
+ready block runs one jitted fixed-shape step — fingerprint, sign, expire,
+insert, query — and the emitted pairs either accumulate host-side (parity
+mode) or flow through the station's ``RollingPairFilter`` (bounded mode).
+``StreamingDetector`` composes the S·C streams of S stations and finishes
+with the *same* alignment stack as the offline path: the occurrence
+filter per component, the channel merge of a station's components
+(§7.1), ``cluster_station``, and the network association over stations,
+so a streamed trace yields the same detections as a batch re-run, at
+O(chunk) cost per arrival instead of O(history).
 
 Hot path anatomy (the one-dispatch invariant, ISSUE 3): with
 ``StreamConfig.fused`` (the default) the steady-state per-block work is a
@@ -48,6 +51,7 @@ reproduces the uninterrupted run's detections exactly.
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -251,11 +255,11 @@ def host_occurrence_filter(pairs: Pairs, n_fp: int, lcfg: LSHConfig, *,
                            ) -> tuple[Pairs, jax.Array]:
     """The host-side §6.5 occurrence filter over an accumulated pair set.
 
-    The one shared invocation behind every host-side call site — the
-    parity-mode ``finalize``, the rolling per-window filter, and the
+    The one shared invocation behind the parity-mode ``finalize`` and the
     batch replay driver (``core.detect.detect_events``) — kept as the
     bit-exact reference/fallback for the in-dispatch occurrence limiter
-    (``index.occurrence_limit_pairs``). ``base`` rebases ids into a
+    (``index.occurrence_limit_pairs``); the rolling per-window filter
+    runs its numpy twin ``lsh.occurrence_keep``. ``base`` rebases ids into a
     static [0, n_fp) span first (the rolling filter's window-local id
     space) and restores the original ids on the way out; ``limit``
     overrides the ``frac * n_fp`` occurrence cap when the partition whose
@@ -279,34 +283,55 @@ def host_occurrence_filter(pairs: Pairs, n_fp: int, lcfg: LSHConfig, *,
 
 
 class RollingPairFilter:
-    """Rolling per-window §6.5 occurrence filter + clustering.
+    """One station's rolling per-window §6.5 occurrence filter, channel
+    merge and clustering.
 
-    Every emitted pair is assigned to the window of its *later* member (the
-    query id that emitted it). Once the processed-id frontier passes a
-    window's end, no further pair can land in it, so the window closes:
-    the occurrence filter runs over its pairs with ids rebased into the
-    static [w_start - lookback, w_start + window) span (the sliding index
-    window guarantees partners reach back at most ``lookback``), survivors
-    are channel-merged and diagonal-clustered exactly like finalize, and
-    only the resulting compact event rows are retained. Buffered host pair
-    state is therefore O(window) for an unbounded stream — the streaming
-    analogue of the paper's partition-bounded post-processing. Rows handed
-    out (``rows_tail``/``all_rows``) pass the cross-window
+    A station records ``channels`` components, each a stream of its own
+    through the step; ``add`` takes one component's triplets, tagged with
+    the component, and ``advance`` that component's processed-id
+    frontier. Every emitted pair is assigned to the window of its *later*
+    member (the query id that emitted it). Once every component's
+    frontier has passed a window's end, no further pair can land in it,
+    so the window closes (the paper's §7.1 channel level, window by
+    window):
+
+    * per component, the occurrence filter runs over its pairs with ids
+      rebased into the static [w_start - lookback, w_start + window) span
+      (the sliding index window guarantees partners reach back at most
+      ``lookback``);
+    * the survivors' similarities are summed over the components for each
+      (dt, idx1) and the sum thresholded (``channel_threshold``);
+    * the merged triplets are diagonal-clustered exactly like finalize,
+      and only the resulting compact event rows are retained.
+
+    The close runs in numpy on the host (``lsh.occurrence_keep``,
+    ``align.merge_channels_host``, ``align.cluster_station_host``, each
+    bit for bit its jitted twin), so no close compiles, whatever its
+    size. With a ``telemetry`` hub each close is a ``filter_close`` span
+    (children ``occurrence``, ``merge``, ``cluster``) and feeds the
+    ``filter_*`` counters. Buffered host pair state is O(window) for an
+    unbounded stream — the streaming analogue of the paper's
+    partition-bounded post-processing. Rows handed out
+    (``rows_tail``/``all_rows``) pass the cross-window
     ``merge_boundary_rows`` pass first, so clusters split at a window
     close re-merge before association.
     """
 
     def __init__(self, cfg: DetectConfig, window: int, lookback: int,
-                 pad_to: int = 1024):
+                 channels: int = 1, telemetry: StreamTelemetry | None = None):
         if window <= 0 or lookback <= 0:
             raise ValueError(f"need positive filter window and lookback, "
                              f"got {window}, {lookback}")
         self.cfg = cfg
         self.window = int(window)
         self.lookback = int(lookback)
-        self.pad_to = pad_to
+        self.channels = int(channels)
+        self.telemetry = telemetry
         self.w_start = 0
-        self.buf: list[np.ndarray] = []     # open-window (m, 3) triplets
+        # open-window (m, 3) triplets and processed-id frontier per component
+        self.bufs: list[list[np.ndarray]] = [[] for _ in range(channels)]
+        self.comp_rows = [0] * self.channels
+        self.frontier = [0] * self.channels
         self.buf_rows = 0
         self.peak_rows = 0
         self.event_rows: list[np.ndarray] = []  # closed (k, 5) rows, active
@@ -315,25 +340,35 @@ class RollingPairFilter:
         self.pairs_seen = 0
         self.pairs_kept = 0
 
-    def add(self, tri: np.ndarray) -> None:
+    @property
+    def buf(self) -> list[np.ndarray]:
+        """Every component's open-window triplets."""
+        return [t for b in self.bufs for t in b]
+
+    def add(self, tri: np.ndarray, component: int = 0) -> None:
         tri = np.asarray(tri).reshape(-1, 3)
         if tri.shape[0]:
-            self.buf.append(tri)
+            self.bufs[component].append(tri)
+            self.comp_rows[component] += tri.shape[0]
             self.buf_rows += tri.shape[0]
             self.peak_rows = max(self.peak_rows, self.buf_rows)
             self.pairs_seen += tri.shape[0]
 
-    def advance(self, frontier: int) -> int:
-        """Close every window whose end the processed frontier has passed."""
+    def advance(self, frontier: int, component: int = 0) -> int:
+        """Move one component's processed frontier; close every window
+        whose end every component has passed."""
+        self.frontier[component] = frontier
+        done = min(self.frontier)
         closed = 0
-        while frontier >= self.w_start + self.window:
+        while done >= self.w_start + self.window:
             self._close(self.w_start + self.window)
             closed += 1
         return closed
 
     def close_all(self, frontier: int) -> None:
         """Flush the open tail window (finalize boundary)."""
-        self.advance(frontier)
+        for c in range(self.channels):
+            self.advance(frontier, c)
         if self.buf_rows:
             self._close(self.w_start + self.window)
 
@@ -378,50 +413,82 @@ class RollingPairFilter:
         return merge_boundary_rows(np.concatenate(rows, axis=0),
                                    self.cfg.align)
 
+    def _span(self, name: str, **attrs):
+        if self.telemetry is None:
+            return contextlib.nullcontext()
+        return self.telemetry.tracer.span(name, **attrs)
+
     def _close(self, w_end: int) -> None:
-        tri = (np.concatenate(self.buf, axis=0) if self.buf
-               else np.zeros((0, 3), np.int64))
-        in_w = tri[:, 1] < w_end
-        cur, rest = tri[in_w], tri[~in_w]
-        self.buf = [rest] if rest.shape[0] else []
-        self.buf_rows = int(rest.shape[0])
-        if cur.shape[0]:
-            rows = self._filter_cluster(cur)
-            if rows.shape[0]:
-                self.event_rows.append(rows)
+        with self._span("filter_close", window=self.windows_closed):
+            cur = []
+            for c, buf in enumerate(self.bufs):
+                tri = (np.concatenate(buf, axis=0) if buf
+                       else np.zeros((0, 3), np.int64))
+                in_w = tri[:, 1] < w_end
+                rest = tri[~in_w]
+                cur.append(tri[in_w])
+                self.bufs[c] = [rest] if rest.shape[0] else []
+                self.comp_rows[c] = int(rest.shape[0])
+            self.buf_rows = sum(self.comp_rows)
+            kept, merged = [0] * self.channels, 0
+            if any(t.shape[0] for t in cur):
+                rows, kept, merged = self._filter_cluster(cur)
+                if rows.shape[0]:
+                    self.event_rows.append(rows)
         self.w_start = w_end
         self.windows_closed += 1
+        if self.telemetry is not None:
+            self.telemetry.record_filter_close(kept, merged)
 
-    def _filter_cluster(self, tri: np.ndarray) -> np.ndarray:
-        """One window's triplets → occurrence-filtered clustered rows."""
+    def _filter_cluster(self, tris: list[np.ndarray]
+                        ) -> tuple[np.ndarray, list[int], int]:
+        """One window's triplets, per component → (occurrence-filtered,
+        channel-merged, clustered rows; triplets into the merge per
+        component; merged triplets kept)."""
         lcfg, acfg = self.cfg.lsh, self.cfg.align
-        pairs = pairs_from_triplets(tri, self.pad_to)
-        if lcfg.occurrence_frac > 0:
-            pairs, _ = host_occurrence_filter(
-                pairs, self.lookback + self.window, lcfg,
-                base=self.w_start - self.lookback,
-                limit=max(1, int(lcfg.occurrence_frac * self.window)))
-        self.pairs_kept += int(pairs.count())
-        merged = align_mod.merge_channels(
-            [(pairs.dt, pairs.idx1, pairs.sim, pairs.valid)],
-            acfg.channel_threshold)
-        events = align_mod.cluster_station(merged, acfg)
-        return events_to_rows(events)
+        with self._span("occurrence"):
+            if lcfg.occurrence_frac > 0:
+                base = self.w_start - self.lookback
+                limit = max(1, int(lcfg.occurrence_frac * self.window))
+                tris = [t[lsh_mod.occurrence_keep(
+                    t[:, 0] - base, t[:, 1] - base,
+                    self.lookback + self.window, lcfg.occurrence_frac,
+                    limit=limit)] for t in tris]
+        kept = [int(t.shape[0]) for t in tris]
+        self.pairs_kept += sum(kept)
+        with self._span("merge"):
+            merged = align_mod.merge_channels_host(tris,
+                                                   acfg.channel_threshold)
+        with self._span("cluster"):
+            rows = align_mod.cluster_station_host(merged, acfg)
+        return rows, kept, int(merged.shape[0])
 
     def snapshot(self) -> tuple[dict, dict]:
-        buf = (np.concatenate(self.buf, axis=0).astype(np.int64)
-               if self.buf else np.zeros((0, 3), np.int64))
         rows = self.archive_rows + self.event_rows
         raw = (np.concatenate(rows, axis=0) if rows
                else np.zeros((0, 5), np.int64))
-        return ({"buf": buf, "events": raw},
-                {"w_start": self.w_start, "windows_closed":
-                 self.windows_closed, "pairs_seen": self.pairs_seen,
-                 "pairs_kept": self.pairs_kept, "peak_rows": self.peak_rows})
+        comp = [np.full(n, c, np.int64) for c, n in enumerate(self.comp_rows)]
+        buf = self.buf
+        arrays = {"buf": (np.concatenate(buf, axis=0).astype(np.int64)
+                          if buf else np.zeros((0, 3), np.int64)),
+                  "events": raw}
+        scalars = {"w_start": self.w_start, "windows_closed":
+                   self.windows_closed, "pairs_seen": self.pairs_seen,
+                   "pairs_kept": self.pairs_kept, "peak_rows": self.peak_rows}
+        if self.channels > 1:
+            arrays["buf_component"] = np.concatenate(comp)
+            scalars["frontier"] = list(self.frontier)
+        return arrays, scalars
 
     def restore(self, arrays: dict, scalars: dict) -> None:
         buf = np.asarray(arrays["buf"], np.int64).reshape(-1, 3)
-        self.buf = [buf] if buf.shape[0] else []
+        comp = np.asarray(arrays.get("buf_component",
+                                     np.zeros(buf.shape[0], np.int64)))
+        self.bufs = [[] for _ in range(self.channels)]
+        for c in range(self.channels):
+            part = buf[comp == c]
+            self.bufs[c] = [part] if part.shape[0] else []
+            self.comp_rows[c] = int(part.shape[0])
         self.buf_rows = int(buf.shape[0])
         rows = np.asarray(arrays["events"], np.int64).reshape(-1, 5)
         self.archive_rows = []
@@ -431,6 +498,7 @@ class RollingPairFilter:
         self.pairs_seen = int(scalars["pairs_seen"])
         self.pairs_kept = int(scalars["pairs_kept"])
         self.peak_rows = int(scalars["peak_rows"])
+        self.frontier = list(scalars.get("frontier", [0] * self.channels))
 
 
 # per-chunk wall samples retained for the percentile view; older samples
@@ -473,18 +541,24 @@ class StreamStats:
 
 
 class StationStream:
-    """Incremental detection state for a single station.
+    """Incremental detection state for one stream: one component of a
+    station (a one-component station is one stream).
 
     ``external=True`` (set by a pooled ``StreamingDetector``) keeps only
-    host-side state here — ring framing, reservoir, rolling filter,
-    stats — while the owner steps the device state through the vmapped
-    station pool and feeds this station's slice back via ``_consume``.
+    host-side state here — ring framing, reservoir, stats — while the
+    owner steps the device state through the vmapped pool and feeds this
+    stream's slice back via ``_consume``. In bounded mode the stream's
+    triplets go to its station's ``RollingPairFilter`` (``filter``,
+    shared by the station's components; its own when not given), tagged
+    with its ``channel``.
     """
 
     def __init__(self, cfg: DetectConfig, scfg: StreamConfig,
                  med_mad: tuple[np.ndarray, np.ndarray] | None = None,
                  external: bool = False,
-                 telemetry: StreamTelemetry | None = None):
+                 telemetry: StreamTelemetry | None = None,
+                 channel: int = 0,
+                 pair_filter: RollingPairFilter | None = None):
         self.cfg = cfg
         self.scfg = scfg
         # detector-shared telemetry hub; a standalone station gets its own
@@ -528,9 +602,12 @@ class StationStream:
         self._dup_map: dict[int, int] = {}   # hash -> newest fp id
         self.triplets: list[np.ndarray] = []            # (m, 3) idx1,idx2,sim
         self.rolling = scfg.filter_window_fingerprints > 0
-        self.filter = (RollingPairFilter(cfg, scfg.filter_window_fingerprints,
-                                         scfg.window_fingerprints)
-                       if self.rolling else None)
+        self.channel = channel
+        if self.rolling and pair_filter is None:
+            pair_filter = RollingPairFilter(
+                cfg, scfg.filter_window_fingerprints,
+                scfg.window_fingerprints, telemetry=self.telemetry)
+        self.filter = pair_filter
         self.processed_fp = 0       # ids fully through the jitted step
         self._tri_rows = 0
         self.peak_tri_rows = 0
@@ -568,7 +645,8 @@ class StationStream:
     def host_state_rows(self) -> int:
         """Candidate triplet rows currently buffered host-side — the
         quantity the rolling filter bounds."""
-        return self.filter.buf_rows if self.rolling else self._tri_rows
+        return (self.filter.comp_rows[self.channel] if self.rolling
+                else self._tri_rows)
 
     def quality_summary(self) -> dict:
         """Ingest reconciliation + in-dispatch guard counters (ISSUE 4),
@@ -831,12 +909,12 @@ class StationStream:
         if m:
             tri = np.stack([i1[pv], i2[pv], sim[pv]], axis=1).astype(np.int64)
             if self.rolling:
-                self.filter.add(tri)
+                self.filter.add(tri, self.channel)
             else:
                 self.triplets.append(tri)
                 self._tri_rows += m
         if self.rolling:
-            self.filter.advance(self.processed_fp)
+            self.filter.advance(self.processed_fp, self.channel)
             self.peak_tri_rows = max(self.peak_tri_rows,
                                      self.filter.peak_rows)
         else:
@@ -892,42 +970,12 @@ class StationStream:
         return pairs_from_triplets(tri, pad_to)
 
     def finalize(self) -> tuple[Events, Pairs, dict]:
-        """Occurrence filter + channel merge + diagonal clustering.
-
-        Parity mode runs the offline reduction over the full accumulated
-        pair set. Bounded mode closes the open rolling window and returns
-        the concatenation of per-window events (boundary-merged); raw
-        pairs were already retired window-by-window, so the returned
-        ``Pairs`` is empty.
-        """
+        """Occurrence filter + channel merge + diagonal clustering of this
+        stream as a one-component station (``finalize_station``); the
+        returned ``Pairs`` are its occurrence-filtered pairs."""
         self.flush()
-        lcfg, acfg = self.cfg.lsh, self.cfg.align
-        n_fp = self.ring.next_fp
-        if self.rolling:
-            self.filter.close_all(self.processed_fp)
-            events = events_from_rows(self.filter.all_rows())
-            fstats = {
-                "fingerprints": n_fp,
-                "pairs": self.filter.pairs_kept,
-                "windows": self.filter.windows_closed,
-                "events": int(events.count()),
-                "peak_buffered_triplets": self.peak_tri_rows,
-                "quality": self.quality_summary(),
-            }
-            return events, pairs_from_triplets(np.zeros((0, 3))), fstats
-        pairs = self.accumulated_pairs()
-        fstats = {"fingerprints": n_fp, "quality": self.quality_summary()}
-        if lcfg.occurrence_frac > 0 and n_fp > 0:
-            pairs, excluded = host_occurrence_filter(pairs, n_fp, lcfg)
-            fstats["excluded_fingerprints"] = int(excluded.sum())
-        merged = align_mod.merge_channels(
-            [(pairs.dt, pairs.idx1, pairs.sim, pairs.valid)],
-            acfg.channel_threshold)
-        events = align_mod.cluster_station(merged, acfg)
-        fstats["pairs"] = int(pairs.count())
-        fstats["events"] = int(events.count())
-        fstats["peak_buffered_triplets"] = self.peak_tri_rows
-        return events, pairs, fstats
+        events, pairs, fstats = finalize_station([self])
+        return events, pairs[0], fstats
 
     # -- snapshot / restore -------------------------------------------------
 
@@ -986,12 +1034,12 @@ class StationStream:
                 arrays["pending/coeffs"] = np.stack(
                     [np.asarray(c) for _, _, c, _ in self.pending]) \
                     .astype(np.float32)
-        if self.rolling:
+        if self.rolling and self.channel == 0:
+            # the station's filter, once (its components share it)
             f_a, f_s = self.filter.snapshot()
-            arrays["filter/buf"] = f_a["buf"]
-            arrays["filter/events"] = f_a["events"]
+            arrays.update({f"filter/{k}": v for k, v in f_a.items()})
             extra["filter"] = f_s
-        else:
+        elif not self.rolling:
             arrays["triplets"] = (
                 np.concatenate(self.triplets, axis=0).astype(np.int64)
                 if self.triplets else np.zeros((0, 3), np.int64))
@@ -1071,9 +1119,10 @@ class StationStream:
                  _mask(i))
                 for i in range(bases.shape[0])]
         if self.rolling:
-            self.filter.restore(
-                {"buf": arrays["filter/buf"],
-                 "events": arrays["filter/events"]}, extra["filter"])
+            if self.channel == 0:
+                self.filter.restore(
+                    {k[len("filter/"):]: v for k, v in arrays.items()
+                     if k.startswith("filter/")}, extra["filter"])
             self.triplets = []
             self._tri_rows = 0
         else:
@@ -1096,6 +1145,72 @@ class StationStream:
                                            maxlen=WALL_WINDOW))
 
 
+def align_station(comps: list[Pairs], n_fp: int, cfg: DetectConfig
+                  ) -> tuple[Events, list[Pairs], int]:
+    """The offline reduction of one station over each component's whole
+    pair set (ids in [0, n_fp)): the §6.5 occurrence filter per
+    component, the channel merge, diagonal clustering. Shared by the
+    parity-mode finalize and the batch replay (``detect_events``).
+    Returns (events, filtered pairs per component, fingerprints excluded
+    over the components)."""
+    lcfg, acfg = cfg.lsh, cfg.align
+    excluded = 0
+    if lcfg.occurrence_frac > 0 and n_fp > 0:
+        filtered = []
+        for pairs in comps:
+            pairs, exc = host_occurrence_filter(pairs, n_fp, lcfg)
+            excluded += int(exc.sum())
+            filtered.append(pairs)
+        comps = filtered
+    merged = align_mod.merge_channels(
+        [(p.dt, p.idx1, p.sim, p.valid) for p in comps],
+        acfg.channel_threshold)
+    return align_mod.cluster_station(merged, acfg), comps, excluded
+
+
+def finalize_station(streams: list[StationStream]
+                     ) -> tuple[Events, list[Pairs], dict]:
+    """One station's events from its component streams, flushed:
+    occurrence filter per component, channel merge, diagonal clustering.
+
+    Parity mode runs the offline reduction over each component's full
+    accumulated pair set, then sums each (dt, idx1) similarity over the
+    components and thresholds the sum (``merge_channels``). Bounded mode
+    closes the station filter's open window and returns the concatenation
+    of its per-window events (boundary-merged); raw pairs were already
+    retired window by window, so the returned ``Pairs`` are empty. Returns
+    (events, per-component filtered ``Pairs``, stats).
+    """
+    head = streams[0]
+    lcfg = head.cfg.lsh
+    n_fp = head.ring.next_fp
+    quality = merge_counts(st.quality_summary() for st in streams)
+    peak = max(st.peak_tri_rows for st in streams)
+    if head.rolling:
+        filt = head.filter
+        filt.close_all(min(st.processed_fp for st in streams))
+        events = events_from_rows(filt.all_rows())
+        fstats = {
+            "fingerprints": n_fp,
+            "pairs": filt.pairs_kept,
+            "windows": filt.windows_closed,
+            "events": int(events.count()),
+            "peak_buffered_triplets": peak,
+            "quality": quality,
+        }
+        empty = pairs_from_triplets(np.zeros((0, 3)))
+        return events, [empty] * len(streams), fstats
+    fstats = {"fingerprints": n_fp, "quality": quality}
+    events, comps, excluded = align_station(
+        [st.accumulated_pairs() for st in streams], n_fp, head.cfg)
+    if lcfg.occurrence_frac > 0 and n_fp > 0:
+        fstats["excluded_fingerprints"] = excluded
+    fstats["pairs"] = sum(int(p.count()) for p in comps)
+    fstats["events"] = int(events.count())
+    fstats["peak_buffered_triplets"] = peak
+    return events, comps, fstats
+
+
 def _per_station_stats(med_mad, n_stations: int) -> list:
     """Frozen statistics as one (med, mad) pair per station: ``med_mad``
     is a shared pair of (n_coeff,) arrays, or (S, n_coeff) arrays with one
@@ -1114,26 +1229,41 @@ def _per_station_stats(med_mad, n_stations: int) -> list:
 class StreamingDetector:
     """Multi-station streaming FAST: push chunks, read detections.
 
-    ``push`` accepts (n_stations, chunk_len) or a 1-D chunk for a single
-    station; chunk lengths may vary call to call. ``finalize`` runs the
-    per-station alignment and (when n_stations ≥ 2) the network
-    association, mirroring ``detect_events``. In bounded mode each push
-    also polls the incremental association: newly final multi-station
-    detections land in ``alerts`` as they close, not only at finalize.
+    Each of ``n_stations`` stations records ``channels`` components (the
+    paper's Z, N, E; default 1), and each component is one stream,
+    fingerprinted, hashed and searched on its own (arXiv:1803.09835
+    §5–§6). ``stations`` holds the S·C per-stream ``StationStream``
+    objects, station-major and component-minor (stream ``i`` is component
+    ``i % channels`` of station ``i // channels``); ``filters`` holds one
+    ``RollingPairFilter`` per station in bounded mode, which merges its
+    components' pairs (§7.1 channel level) before clustering.
 
-    With ``StreamConfig.pooled`` (the default) and ≥2 stations, the
-    per-station device states are stacked into one pool and every ready
-    block steps all stations through a single vmapped fused dispatch —
-    the per-station ``StationStream`` objects keep only host-side state
-    (ring framing, reservoir, rolling filter, stats).
+    ``push`` accepts (S, C, chunk_len), (S·C, chunk_len), or a 1-D chunk
+    for a single stream; chunk lengths may vary call to call.
+    ``finalize`` runs the per-station alignment and (when S ≥ 2) the
+    network association over stations, mirroring ``detect_events``. In
+    bounded mode each push also polls the incremental association: newly
+    final multi-station detections land in ``alerts`` as they close, not
+    only at finalize.
+
+    With ``StreamConfig.pooled`` (the default) and ≥2 streams, the
+    per-stream device states are stacked into one pool and every ready
+    block steps all streams through a single vmapped fused dispatch —
+    the ``StationStream`` objects keep only host-side state (ring
+    framing, reservoir, stats). ``med_mad`` is per stream; ``station_xy``
+    is per station.
     """
 
     def __init__(self, cfg: DetectConfig, scfg: StreamConfig | None = None,
-                 n_stations: int = 1,
+                 n_stations: int = 1, channels: int = 1,
                  med_mad: tuple[np.ndarray, np.ndarray] | None = None,
                  station_xy: np.ndarray | None = None):
+        if channels < 1:
+            raise ValueError(f"channels must be positive, got {channels}")
         self.cfg = cfg
         self.scfg = scfg or StreamConfig()
+        self.channels = int(channels)
+        n_streams = n_stations * self.channels
         self.station_xy = (np.asarray(station_xy, np.float32)
                            if station_xy is not None else None)
         if self.station_xy is not None \
@@ -1146,22 +1276,26 @@ class StreamingDetector:
                          and self.station_xy is not None
                          and n_stations >= 2)
         self.pooled = (self.scfg.fused and self.scfg.pooled
-                       and n_stations >= 2)
+                       and n_streams >= 2)
         # sharded station pool (ISSUE 10): the capability probe returns a
         # 1-axis ``stations`` mesh when >1 device is visible, else None —
         # the None keeps every pool dispatch on the single-device vmap
         # path. The pool is padded up to a multiple of the mesh width
         # with throwaway station rows (row-independent math; their output
         # is never read) so the leading axis always divides the mesh.
-        self.mesh = (dist.station_mesh(n_stations)
+        self.mesh = (dist.station_mesh(n_streams)
                      if self.pooled and self.scfg.sharded else None)
-        self.pool_pad = dist.padded_pool_width(n_stations,
-                                               self.mesh) - n_stations
-        self.telemetry = StreamTelemetry(n_stations)
-        self.stations = [StationStream(cfg, self.scfg, med_mad=mm,
-                                       external=self.pooled,
-                                       telemetry=self.telemetry)
-                         for mm in _per_station_stats(med_mad, n_stations)]
+        self.pool_pad = dist.padded_pool_width(n_streams,
+                                               self.mesh) - n_streams
+        self.telemetry = StreamTelemetry(n_streams)
+        self.rolling = self.scfg.filter_window_fingerprints > 0
+        self.filters = [self._station_filter() for _ in range(n_stations)]
+        self.stations = [
+            StationStream(cfg, self.scfg, med_mad=mm, external=self.pooled,
+                          telemetry=self.telemetry,
+                          channel=i % self.channels,
+                          pair_filter=self.filters[i // self.channels])
+            for i, mm in enumerate(_per_station_stats(med_mad, n_streams))]
         self.pstate: fused_mod.FusedState | None = None
         self._halo_ok = False
         self.mappings = self.stations[0].mappings
@@ -1169,7 +1303,6 @@ class StreamingDetector:
             st._owner, st._pool_idx = self, i
         if self.pooled and med_mad is not None:
             self._build_pool()
-        self.rolling = self.scfg.filter_window_fingerprints > 0
         self.alerts: list[np.ndarray] = []   # (k, ALERT_COLS) rows
         # alerted keys + the best station multiplicity each has alerted
         # at: (dt, onset, best_n_stations). A group whose multiplicity
@@ -1189,31 +1322,54 @@ class StreamingDetector:
         # pool_serving_state() refreshes on "did anything arrive?"
         self.serving_version = 0
 
+    @property
+    def n_stations(self) -> int:
+        return len(self.stations) // self.channels
+
+    def station_streams(self, station: int) -> list[StationStream]:
+        """The component streams of one station."""
+        c = self.channels
+        return self.stations[station * c:(station + 1) * c]
+
+    def _station_filter(self) -> RollingPairFilter | None:
+        if not self.rolling:
+            return None
+        return RollingPairFilter(self.cfg,
+                                 self.scfg.filter_window_fingerprints,
+                                 self.scfg.window_fingerprints,
+                                 channels=self.channels,
+                                 telemetry=self.telemetry)
+
     def push(self, chunk: np.ndarray, offset: int | None = None) -> int:
         """Ingest one network chunk; ``offset`` places it at an absolute
-        sample offset on every station's timeline (late / duplicated /
-        gapped telemetry is reconciled per station by the rings; chunks
-        are network-aligned, so one offset serves all stations — a
+        sample offset on every stream's timeline (late / duplicated /
+        gapped telemetry is reconciled per stream by the rings; chunks
+        are network-aligned, so one offset serves all streams — a
         single-station outage is NaN samples inside the chunk)."""
         chunk = np.asarray(chunk, np.float32)
-        if chunk.ndim == 1:
-            chunk = chunk[None, :]
-        assert chunk.shape[0] == len(self.stations), \
-            (chunk.shape, len(self.stations))
+        chunk = chunk.reshape(int(np.prod(chunk.shape[:-1])),
+                              chunk.shape[-1])
+        if chunk.shape[0] != len(self.stations):
+            raise ValueError(f"chunk holds {chunk.shape[0]} streams, the "
+                             f"detector {self.n_stations} stations x "
+                             f"{self.channels} components")
         self.telemetry.start()
         with self.telemetry.tracer.span(
-                "chunk", stations=len(self.stations)) as sp:
+                "chunk", stations=self.n_stations) as sp:
             if self.locating:
                 pos = (self.stations[0].ring.frontier if offset is None
                        else int(offset))
-                for i in range(chunk.shape[0]):
-                    self._note_amps(i, pos, chunk[i])
+                c = self.channels
+                for i in range(self.n_stations):
+                    # a station's amplitude is its loudest component's
+                    self._note_amps(i, pos, np.fmax.reduce(
+                        np.abs(chunk[i * c:(i + 1) * c]), axis=0))
             if self.pooled:
                 emitted = self._pool_push(chunk, offset)
             else:
                 emitted = sum(st._push(chunk[i], offset)
                               for i, st in enumerate(self.stations))
-            if self.rolling and len(self.stations) >= 2:
+            if self.rolling and self.n_stations >= 2:
                 with self.telemetry.tracer.span("detections"):
                     new = self.poll_detections()
                 if new.shape[0]:
@@ -1511,7 +1667,8 @@ class StreamingDetector:
 
     def add_station(self, med_mad: tuple[np.ndarray, np.ndarray]
                     | None = None) -> int:
-        """Elastically grow the live pool by one station; returns the new
+        """Elastically grow the live pool by one station (its
+        ``channels`` component streams together); returns the new
         station's index.
 
         The stacked pytree is re-padded and re-sharded for the new width
@@ -1519,11 +1676,13 @@ class StreamingDetector:
         frontier: its ring mirrors a peer's framing position with the
         whole pre-join span marked missing, so lockstep block emission
         (shared base ids) holds and the join span is suppressed
-        in-dispatch rather than invented. ``med_mad`` defaults to station
-        0's frozen statistics (network stations see similar noise floors;
-        pass real statistics for production use). Serving engines built
-        over the old width keep serving their snapshot — rebuild them to
-        pick up the grown pool (``ServeDetectEngine`` pins its width).
+        in-dispatch rather than invented. ``med_mad`` is one shared pair
+        or (channels, n_coeff) rows, one per component; it defaults to
+        station 0's frozen statistics (network stations see similar noise
+        floors; pass real statistics for production use). Serving engines
+        built over the old width keep serving their snapshot — rebuild
+        them to pick up the grown pool (``ServeDetectEngine`` pins its
+        width).
         """
         if not self.pooled:
             raise ValueError(
@@ -1540,32 +1699,38 @@ class StreamingDetector:
                 "add_station requires a live pool (statistics frozen and "
                 "the stacked state built); push warmup chunks first")
         if med_mad is None:
-            med_mad = tuple(np.asarray(m)
-                            for m in self.stations[0].med_mad)
+            med_mad = tuple(np.stack([np.asarray(st.med_mad[k])
+                                      for st in self.station_streams(0)])
+                            for k in (0, 1))
         self._materialize_stations()
-        st = StationStream(self.cfg, self.scfg, med_mad=med_mad,
-                           external=True, telemetry=self.telemetry)
-        st._owner, st._pool_idx = self, len(self.stations)
+        filt = self._station_filter()
         peer = self.stations[0]
-        st.ring.start = peer.ring.start
-        st.ring.next_fp = peer.ring.next_fp
-        st.ring.buf = np.zeros(peer.ring.buf.size, np.float32)
-        st.ring.vbuf = np.zeros(peer.ring.buf.size, bool)
-        st.ring.quality["missing_samples"] += int(peer.ring.buf.size)
-        st.processed_fp = peer.processed_fp
-        if st.rolling and st.processed_fp:
-            st.filter.advance(st.processed_fp)  # join cost paid up front
-        self.stations.append(st)
+        for c, mm in enumerate(_per_station_stats(med_mad, self.channels)):
+            st = StationStream(self.cfg, self.scfg, med_mad=mm,
+                               external=True, telemetry=self.telemetry,
+                               channel=c, pair_filter=filt)
+            st._owner, st._pool_idx = self, len(self.stations)
+            st.ring.start = peer.ring.start
+            st.ring.next_fp = peer.ring.next_fp
+            st.ring.buf = np.zeros(peer.ring.buf.size, np.float32)
+            st.ring.vbuf = np.zeros(peer.ring.buf.size, bool)
+            st.ring.quality["missing_samples"] += int(peer.ring.buf.size)
+            st.processed_fp = peer.processed_fp
+            if st.rolling and st.processed_fp:
+                filt.advance(st.processed_fp, c)  # join cost paid up front
+            self.stations.append(st)
+        self.filters.append(filt)
         self._amp.append({})
         self._repack_pool()
         self.serving_version += 1
-        return st._pool_idx
+        return self.n_stations - 1
 
     def remove_station(self, station: int) -> None:
-        """Elastically drop one station from the live pool (its index
-        state and host buffers are discarded; remaining stations shift
-        down, which renumbers pair/event station indices from here on).
-        The pool is re-padded and re-sharded for the new width."""
+        """Elastically drop one station, all its component streams, from
+        the live pool (their index state and host buffers are discarded;
+        remaining stations shift down, which renumbers pair/event station
+        indices from here on). The pool is re-padded and re-sharded for
+        the new width."""
         if not self.pooled or self.pstate is None:
             raise ValueError("remove_station requires a live pooled "
                              "detector (statistics frozen)")
@@ -1573,14 +1738,17 @@ class StreamingDetector:
             raise ValueError(
                 "remove_station cannot shrink the locate tier: "
                 "station_xy geometry is fixed at construction")
-        if not 0 <= station < len(self.stations):
+        if not 0 <= station < self.n_stations:
             raise IndexError(station)
-        if len(self.stations) < 2:
+        if self.n_stations < 2:
             raise ValueError("cannot remove the last station")
         self._materialize_stations()
-        dropped = self.stations.pop(station)
-        dropped._owner = None
-        dropped._state = None
+        for dropped in self.station_streams(station):
+            dropped._owner = None
+            dropped._state = None
+        c = self.channels
+        del self.stations[station * c:(station + 1) * c]
+        self.filters.pop(station)
         self._amp.pop(station)
         for i, st in enumerate(self.stations):
             st._pool_idx = i
@@ -1623,10 +1791,12 @@ class StreamingDetector:
     def _station_weights(self) -> np.ndarray:
         """Live per-station stack weights from the ingest/guard QC
         counters (``core.locate.station_weights``)."""
+        per = [self.station_streams(i) for i in range(self.n_stations)]
         return locate_mod.station_weights(
-            [st.quality_summary() for st in self.stations],
-            [st.stats.samples for st in self.stations],
-            [st.ring.next_fp for st in self.stations], self.cfg.locate)
+            [merge_counts(st.quality_summary() for st in g) for g in per],
+            [sum(st.stats.samples for st in g) for g in per],
+            [sum(st.ring.next_fp for st in g) for g in per],
+            self.cfg.locate)
 
     def _locate_rows(self, rows: np.ndarray, onset_mat: np.ndarray,
                      score_mat: np.ndarray) -> tuple[np.ndarray, int]:
@@ -1680,33 +1850,29 @@ class StreamingDetector:
         remains the authoritative association over the full event history.
         """
         acfg = self.cfg.align
-        if not self.rolling or len(self.stations) < 2:
+        if not self.rolling or self.n_stations < 2:
             return np.zeros((0, ALERT_COLS), np.int64)
         # the active rows only change when a window closes — don't repeat
-        # the association dispatch on pushes that closed nothing
-        closed = sum(st.filter.windows_closed for st in self.stations)
+        # the association on pushes that closed nothing
+        closed = sum(f.windows_closed for f in self.filters)
         if closed == self._polled_windows:
             return np.zeros((0, ALERT_COLS), np.int64)
         self._polled_windows = closed
-        per_station = [st.filter.rows_tail(self._assoc_lo)
-                       for st in self.stations]
+        per_station = [f.rows_tail(self._assoc_lo) for f in self.filters]
         if sum(r.shape[0] for r in per_station) == 0:
             return np.zeros((0, ALERT_COLS), np.int64)
-        events = [events_from_rows(r) for r in per_station]
-        det = align_mod.associate_network(events, acfg, len(self.stations),
-                                          with_onsets=self.locating)
-        v = np.asarray(det["valid"])
-        rows = np.zeros((int(v.sum()), ALERT_COLS), np.int64)
-        rows[:, 0] = np.asarray(det["dt"])[v]
-        rows[:, 1] = np.asarray(det["onset"])[v]
-        rows[:, 2] = np.asarray(det["n_stations"])[v]
-        rows[:, 3] = np.asarray(det["score"])[v]
+        # on the host, so a poll of any size compiles nothing
+        det = align_mod.associate_network_host(per_station, acfg,
+                                               with_onsets=self.locating)
+        rows = np.zeros((det["dt"].size, ALERT_COLS), np.int64)
+        rows[:, 0] = det["dt"]
+        rows[:, 1] = det["onset"]
+        rows[:, 2] = det["n_stations"]
+        rows[:, 3] = det["score"]
         rows[:, 5:7] = locate_mod.LOC_NONE
         rows[:, 7] = locate_mod.MAG_NONE
-        onset_mat = (np.asarray(det["station_onset"])[v]
-                     if self.locating else None)
-        score_mat = (np.asarray(det["station_score"])[v]
-                     if self.locating else None)
+        onset_mat = det["station_onset"] if self.locating else None
+        score_mat = det["station_score"] if self.locating else None
         if self._emitted.shape[0] and rows.shape[0]:
             near = ((np.abs(rows[:, 0, None] - self._emitted[None, :, 0])
                      <= acfg.dt_tol)
@@ -1737,12 +1903,12 @@ class StreamingDetector:
         # window can gain no further members — stop rescanning them, and
         # archive rows + dedup keys + amplitude bins the floor has passed
         # so the per-push scan stays O(active window) instead of O(stream)
-        frontier = min(st.filter.w_start for st in self.stations)
+        frontier = min(f.w_start for f in self.filters)
         self._assoc_lo = max(self._assoc_lo, frontier
                              - self.scfg.window_fingerprints
                              - 2 * acfg.onset_tol)
-        for st in self.stations:
-            st.filter.retire_below(self._assoc_lo)
+        for f in self.filters:
+            f.retire_below(self._assoc_lo)
         if self._emitted.shape[0]:
             live = self._emitted[:, 1] >= self._assoc_lo - acfg.onset_tol
             self._emitted = self._emitted[live]
@@ -1754,18 +1920,22 @@ class StreamingDetector:
         return rows
 
     def finalize(self) -> tuple[dict | None, list[Events], dict]:
+        """Flush every stream, then per station ``finalize_station`` and
+        (S ≥ 2) the network association over stations."""
         if self.pooled:
             self._pool_flush()
+        for st in self.stations:
+            st.flush()
         station_events, stats = [], {}
-        for i, st in enumerate(self.stations):
-            events, _, fstats = st.finalize()
+        for i in range(self.n_stations):
+            events, _, fstats = finalize_station(self.station_streams(i))
             station_events.append(events)
             for k, v in fstats.items():
                 stats[f"station{i}_{k}"] = v
         detections = None
-        if len(self.stations) >= 2:
+        if self.n_stations >= 2:
             detections = align_mod.associate_network(
-                station_events, self.cfg.align, len(self.stations),
+                station_events, self.cfg.align, self.n_stations,
                 with_onsets=self.locating)
             if self.locating:
                 t0 = time.perf_counter()
@@ -1830,7 +2000,8 @@ class StreamingDetector:
             arrays[f"detector/amp{i}"] = (
                 np.array([[b, a] for b, a in sorted(d.items())], np.float64)
                 if d else np.zeros((0, 2), np.float64))
-        extra = {"n_stations": len(self.stations), "stations": st_extra,
+        extra = {"n_stations": self.n_stations, "channels": self.channels,
+                 "stations": st_extra,
                  "assoc_lo": self._assoc_lo,
                  "telemetry": self.telemetry.snapshot(),
                  "scfg": {
@@ -1870,6 +2041,7 @@ class StreamingDetector:
         """
         arrays, extra, step = ckpt_mod.restore_flat(ckpt_dir, step=step)
         det = cls(cfg, scfg, n_stations=int(extra["n_stations"]),
+                  channels=int(extra.get("channels", 1)),
                   station_xy=station_xy)
         saved = extra.get("scfg", {})
         for key, have in (
@@ -1921,7 +2093,7 @@ class StreamingDetector:
             alerts = np.concatenate([alerts, pad], axis=1)
         alerts = alerts.reshape(-1, ALERT_COLS)
         det.alerts = [alerts] if alerts.shape[0] else []
-        for i in range(len(det.stations)):
+        for i in range(det.n_stations):
             amp = arrays.get(f"detector/amp{i}")
             if amp is not None and amp.size:
                 det._amp[i] = {int(b): float(a)
@@ -1930,8 +2102,7 @@ class StreamingDetector:
         if "telemetry" in extra:    # pre-ISSUE-6 snapshots: fresh registry
             det.telemetry.restore(extra["telemetry"])
         if det.rolling:
-            det._polled_windows = sum(st.filter.windows_closed
-                                      for st in det.stations)
+            det._polled_windows = sum(f.windows_closed for f in det.filters)
         return det, step
 
 

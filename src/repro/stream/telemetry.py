@@ -22,11 +22,15 @@ detection hot path:
   via ``watchdog.events``.
 * **span tracing** — a :class:`~repro.obsv.spans.SpanTracer` carries the
   span tree of every push (``chunk`` → ``ingest``/``dedup``,
-  ``fused_step``/``put``/``dispatch``/``wait``/``pull``, ``host_tail``,
-  ``detections``); each span is also a profiler annotation, and records
-  (ids, parents, realtime ns) buffer in memory for an optional JSONL
-  sink that serving turns on with a flag and flushes at each heartbeat;
-  per-name totals feed ``metrics_snapshot``.
+  ``fused_step``/``put``/``dispatch``/``wait``/``pull``, ``host_tail``
+  and the rolling filter's ``filter_close``/``occurrence``/``merge``/
+  ``cluster``, ``detections``); each span is also a profiler
+  annotation, and records (ids, parents, realtime ns) buffer in memory
+  for an optional JSONL sink that serving turns on with a flag and
+  flushes at each heartbeat; per-name totals feed ``metrics_snapshot``.
+* **rolling filter** — each station's window close records the
+  triplets each component put into the channel merge and the merged
+  triplets kept (``record_filter_close``; ``filter_view``).
 * **health surface** — ``heartbeat(det)`` builds the periodic liveness
   dict (real-time factor, throughput, drop-rate breakdown, quality
   counters) and ``prometheus(det)`` the text exposition, both consumed by
@@ -135,6 +139,32 @@ class StreamTelemetry:
             self.raw_walls["host_tail"].append(wall_s)
         self.registry.histogram("host_tail_wall_seconds",
                                 station=str(station)).record(wall_s)
+
+    # -- rolling filter hooks ------------------------------------------------
+
+    def record_filter_close(self, merge_in: list[int], merged: int) -> None:
+        """One station's filter-window close: the triplets each component
+        put into the channel merge (after its occurrence filter), and the
+        merged triplets that reached the channel threshold."""
+        reg = self.registry
+        reg.counter("filter_windows_closed_total").inc()
+        for c, n in enumerate(merge_in):
+            reg.counter("filter_merge_triplets_total",
+                        component=str(c)).inc(int(n))
+        reg.counter("filter_merged_kept_total").inc(int(merged))
+
+    def filter_view(self, channels: int = 1) -> dict:
+        """Rolling-filter summary: windows closed over every station, the
+        triplets into the channel merge per component, and the merged
+        triplets kept. All-zero until a window closes."""
+        reg = self.registry
+        return {
+            "windows_closed": int(reg.total("filter_windows_closed_total")),
+            "merge_in": [int(reg.counter("filter_merge_triplets_total",
+                                         component=str(c)).value)
+                         for c in range(channels)],
+            "merged_kept": int(reg.total("filter_merged_kept_total")),
+        }
 
     # -- location-tier hooks (ISSUE 9) ---------------------------------------
 
@@ -353,6 +383,7 @@ def metrics_snapshot(det) -> dict:
     return {
         "schema": METRICS_SCHEMA,
         "stations": len(det.stations),
+        "channels": det.channels,
         "uptime_s": round(tel.uptime_s(), 3),
         "stream_s": round(tel.stream_seconds(det), 3),
         "rtf": round(tel.real_time_factor(det), 3),
@@ -375,6 +406,7 @@ def metrics_snapshot(det) -> dict:
                          "locate_stack_wall_seconds")},
         "serve": tel.serve_view(),
         "locate": tel.locate_view(),
+        "filter": tel.filter_view(det.channels),
         "spans": tel.tracer.summary(),
         "watchdog": {"steps": tel.watchdog.n,
                      "stragglers": len(tel.watchdog.events)},

@@ -308,7 +308,9 @@ def test_spans_on_the_profiler_clock(tmp_path):
 _TREE = {"ingest": "chunk", "dedup": "ingest", "fused_step": "chunk",
          "put": "fused_step", "dispatch": "fused_step",
          "wait": "fused_step", "pull": "fused_step",
-         "host_tail": "chunk", "detections": "chunk"}
+         "host_tail": "chunk", "detections": "chunk",
+         "filter_close": "host_tail", "occurrence": "filter_close",
+         "merge": "filter_close", "cluster": "filter_close"}
 
 
 def _check_span_tree(path, expect: set) -> None:
@@ -328,12 +330,15 @@ def _check_span_tree(path, expect: set) -> None:
         assert by_id[r["trace"]]["name"] == "chunk"
         assert up["start_ns"] <= r["start_ns"] <= r["end_ns"] <= up["end_ns"]
     for r in recs:
-        if r["name"] in ("chunk", "fused_step"):
+        if r["name"] in ("chunk", "fused_step", "filter_close"):
             inner = sum(k["dur_s"] for k in kids.get(r["id"], ()))
             assert r["dur_s"] >= inner, r
         if r["name"] == "fused_step":
             assert sorted(k["name"] for k in kids[r["id"]]) == \
                 ["dispatch", "pull", "put", "wait"]
+        if r["name"] == "filter_close" and r["id"] in kids:
+            assert sorted(k["name"] for k in kids[r["id"]]) == \
+                ["cluster", "merge", "occurrence"]
         if r["name"] in ("put", "pull"):
             assert r["bytes"] > 0
     assert all("flagged" in r for r in recs if r["name"] == "dedup")
@@ -349,7 +354,8 @@ def _tree_config():
 def test_push_span_tree_pooled_and_solo(tmp_path):
     """A pooled StreamingDetector and a solo StationStream each give one
     span tree per push: ingest (with dedup), the step's put / dispatch /
-    wait / pull, the host tail, and (pooled) the detections poll."""
+    wait / pull, the host tail (with a ``filter_close`` tree where a
+    window closes), and (pooled) the detections poll."""
     from repro.core.synth import make_dataset
     from repro.stream.engine import StationStream
     cfg = smoke_config()
@@ -379,6 +385,43 @@ def test_push_span_tree_pooled_and_solo(tmp_path):
         st.push(chunk)
     st.telemetry.tracer.close()
     _check_span_tree(tmp_path / "s.jsonl", set(_TREE) - {"detections"})
+
+
+def test_filter_close_counters_count_merge_in_and_out(monkeypatch):
+    """Each station close's triplets into the channel merge (per
+    component, after the occurrence filter) and the merged triplets kept
+    reach the ``filter_*`` counters and ``metrics_snapshot``."""
+    from repro.core import align as align_mod
+    from repro.core.synth import make_dataset
+    cfg = smoke_config()
+    ds = make_dataset(_base_synth(n_stations=2, duration_s=400.0))
+    wf = np.asarray(ds.waveforms, np.float32)
+    rng = np.random.default_rng(0)
+    waves = np.stack([wf, wf + rng.standard_normal(wf.shape)
+                      .astype(np.float32)], axis=1)          # (2, 2, T)
+    into, out = [0, 0], [0]
+    real = align_mod.merge_channels_host
+
+    def merge(tris, threshold):
+        for c, t in enumerate(tris):
+            into[c] += t.shape[0]
+        merged = real(tris, threshold)
+        out[0] += merged.shape[0]
+        return merged
+
+    monkeypatch.setattr(align_mod, "merge_channels_host", merge)
+    det = StreamingDetector(cfg, _tree_config(), n_stations=2, channels=2,
+                            med_mad=_frozen(cfg, wf[0]))
+    for chunk in np.array_split(waves, 5, axis=-1):
+        det.push(chunk)
+    view = det.metrics_snapshot()["filter"]
+    assert view["windows_closed"] == sum(f.windows_closed
+                                         for f in det.filters) >= 4
+    assert view["merge_in"] == into and min(into) > 0
+    assert view["merged_kept"] == out[0] > 0
+    assert sum(into) == sum(f.pairs_kept for f in det.filters)
+    reg = det.telemetry.registry
+    assert reg.total("filter_merge_triplets_total") == sum(into)
 
 
 def test_pool_step_lowering_carries_stage_scopes():
@@ -602,11 +645,11 @@ def test_metrics_snapshot_schema_and_prometheus_surface():
         {k: v for k, v in m2.items() if k not in wall_keys}
     json.dumps(m)                            # artifact-ready
     assert m["schema"] == METRICS_SCHEMA == "stream-metrics/v1"
-    assert set(m) == {"schema", "stations", "uptime_s", "stream_s", "rtf",
-                      "stream", "per_station", "drops", "drop_rates",
-                      "quality", "histograms", "serve", "locate", "spans",
-                      "watchdog"}
-    assert m["stations"] == 1
+    assert set(m) == {"schema", "stations", "channels", "uptime_s",
+                      "stream_s", "rtf", "stream", "per_station", "drops",
+                      "drop_rates", "quality", "histograms", "serve",
+                      "locate", "filter", "spans", "watchdog"}
+    assert m["stations"] == 1 and m["channels"] == 1
     assert set(m["drops"]) == set(QC_FIELDS)
     assert m["quality"] == det.quality_summary()
     assert len(m["per_station"]) == 1
